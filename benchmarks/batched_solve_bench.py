@@ -71,8 +71,9 @@ import time
 import jax
 import numpy as np
 
-from repro.core.dlt import DLTEngine, SystemSpec, solve
-from .common import check, table
+from repro.core.dlt import (DLTEngine, SystemSpec, enable_compile_cache,
+                            solve)
+from .common import COMPILE_CACHE_DIR, check, table
 
 FAMILIES = [
     # label, sources, processors, frontend
@@ -81,15 +82,14 @@ FAMILIES = [
     ("nofrontend  N=2 M=4", 2, 4, False),
 ]
 
-#: The bench session: every pass shares this engine's compiled-shape LRU.
-#: CI exports ENGINE_COMPILE_CACHE (an actions/cache'd directory) so the
-#: smoke also exercises the persistent-compile path across workflow runs.
+#: The bench session: every pass shares this engine's compiled-shape LRU,
+#: and compiles persist across processes (CI caches the directory, so
+#: the smoke also exercises the persistent-compile path).
 #: ENGINE_EXECUTOR selects the execution backend for every pass
 #: ("local" default; the multi-device CI job exports "sharded" under 8
 #: virtual host devices).
-ENGINE = DLTEngine(
-    executor=os.environ.get("ENGINE_EXECUTOR", "local"),
-    compile_cache_dir=os.environ.get("ENGINE_COMPILE_CACHE") or None)
+enable_compile_cache(COMPILE_CACHE_DIR)
+ENGINE = DLTEngine(executor=os.environ.get("ENGINE_EXECUTOR", "local"))
 
 
 def _topology() -> dict:

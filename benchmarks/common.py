@@ -3,10 +3,16 @@
 from __future__ import annotations
 
 import re
+from pathlib import Path
 
 import numpy as np
 
-__all__ = ["table", "check", "Result"]
+__all__ = ["table", "check", "Result", "COMPILE_CACHE_DIR"]
+
+#: Where the benches persist compiled executables when
+#: ``$JAX_COMPILATION_CACHE_DIR`` is unset: one fixed path in the
+#: checkout (git-ignored), so a later process finds them again.
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[1] / ".jax_cache"
 
 
 class Result:

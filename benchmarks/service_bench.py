@@ -57,19 +57,19 @@ import time
 import jax
 import numpy as np
 
-from repro.core.dlt import DLTEngine, SystemSpec, solve
+from repro.core.dlt import (DLTEngine, SystemSpec, enable_compile_cache,
+                            solve)
 from repro.core.dlt.executors import LANE_MICROBATCH
 from repro.serve import (FleetRouter, RouterStats, RouterService,
                          ServiceConfig)
 from repro.serve.engine import route_requests_batch
-from .common import check, table
+from .common import COMPILE_CACHE_DIR, check, table
 
 #: The bench session — every service window and every one-shot reference
 #: solve shares this engine's compiled-shape LRU, exactly as a deployed
 #: router would share the process-wide default session.
-ENGINE = DLTEngine(
-    executor=os.environ.get("ENGINE_EXECUTOR", "local"),
-    compile_cache_dir=os.environ.get("ENGINE_COMPILE_CACHE") or None)
+enable_compile_cache(COMPILE_CACHE_DIR)
+ENGINE = DLTEngine(executor=os.environ.get("ENGINE_EXECUTOR", "local"))
 
 #: One fleet shape for the whole bench (2 frontends, 4 replicas): every
 #: window lands in the same engine size bucket, so the SLO phase runs

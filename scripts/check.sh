@@ -18,9 +18,9 @@
 #    The smoke writes a perf-trajectory JSON (scenarios/sec, warm vs
 #    cold IPM iterations, compile-cache hit/miss counters) to
 #    $BENCH_OUT — CI uploads it as a workflow artifact so the numbers
-#    are tracked per commit.  With ENGINE_COMPILE_CACHE set, compiled
-#    executables persist in that directory across processes (CI caches
-#    it between workflow runs).
+#    are tracked per commit.  Compiled executables persist across
+#    processes in .jax_cache, or in $JAX_COMPILATION_CACHE_DIR when set
+#    (CI caches it between workflow runs).
 #    benchmarks/service_bench.py --smoke then drives the always-on
 #    routing service (async admission queue + deadline batching + drift
 #    re-solves) under a Poisson arrival load, checks window/one-shot

@@ -15,10 +15,7 @@ import numpy as np
 
 from typing import Dict, List, Optional, Sequence, Tuple
 
-try:  # jax >= 0.4.33 exposes the stable alias
-    from jax.extend.core import Literal as _Literal
-except ImportError:  # pragma: no cover - exercised on min-versions CI
-    from jax.core import Literal as _Literal  # type: ignore[attr-defined, no-redef]
+from jax.extend.core import Literal as _Literal
 
 from ...core.dlt.batched import build_banded_family, build_family_lp
 from ...core.dlt.precision import FP32_FACTOR_SCOPE, REFINE_RESIDUAL_SCOPE
@@ -473,15 +470,11 @@ DEFAULT_VMEM_BUDGET = 16 << 20
 
 def _block_bytes(bm) -> int:
     """Bytes of one block window of a pallas operand (mapped dims = 1)."""
-    shape = getattr(bm, "block_shape", None)
-    if shape is None:
-        return 0
-    sdt = getattr(bm, "array_shape_dtype", None)
-    itemsize = np.dtype(sdt.dtype).itemsize if sdt is not None else 8
     n = 1
-    for d in shape:
-        n *= int(d) if isinstance(d, (int, np.integer)) else 1
-    return n * itemsize
+    for d in bm.block_shape:
+        # Blocked(k) windows k elements; Squeezed / Element dims map one
+        n *= int(getattr(d, "block_size", 1))
+    return n * np.dtype(bm.array_aval.dtype).itemsize
 
 
 def pallas_call_vmem_bytes(eqn) -> Optional[int]:

@@ -3,7 +3,7 @@
 The linter never executes a solve: it asks the engine for the exact
 ``(fn, in_axes, args)`` signature an executor would compile for a plan
 (see :meth:`DLTEngine.trace_plan`), traces it to a ClosedJaxpr inside
-the same ``enable_x64`` scope the runtime uses, and optionally lowers
+the same ``x64_scope`` the runtime uses, and optionally lowers
 it to HLO text for the :mod:`repro.analysis.hlo_parse` backend.
 
 :func:`iter_eqns` is the shared jaxpr walker: it yields every equation

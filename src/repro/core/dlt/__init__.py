@@ -9,11 +9,11 @@ Public API:
     monetary_cost, sweep_processors, plan_*        (Sec 6 trade-offs)
     speedup_grid                                   (Sec 5 Amdahl analysis)
     batched_solve, BatchedSystemSpec, ...          (batched vmap engine)
-    compile_cache_info                             (compiled-shape cache ops)
+    compile_cache_info, enable_compile_cache       (compiled-shape cache ops)
 
 Every free function is a thin shim over one shared default
 :class:`~repro.core.dlt.engine.DLTEngine`; configure a session of your
-own (``DLTEngine(formulation=..., compile_cache_dir=...)``) to pin knobs
+own (``DLTEngine(formulation=..., precision=...)``) to pin knobs
 once and reuse warm-started parametric sweeps and the compiled-shape
 cache across the whole workload surface.
 """
@@ -32,6 +32,7 @@ from .engine import (
     DLTEngine,
     EngineConfig,
     EngineStats,
+    enable_compile_cache,
     get_default_engine,
 )
 from .formulations import (
@@ -65,6 +66,7 @@ __all__ = [
     "EngineStats",
     "get_default_engine",
     "compile_cache_info",
+    "enable_compile_cache",
     "solve",
     "batched_solve",
     "solve_lp_batch",

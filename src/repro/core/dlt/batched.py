@@ -192,10 +192,11 @@ def _hsde_ipm_core(c, b, A_mul, AT_mul, make_normal_solver,
     the dense, structured ``[F | I]`` and block-banded instantiations
     share this body.  Shape-static: a while_loop capped at ``max_iter``
     iterations that (under vmap) exits once every lane is decided.
-    Returns (x, obj, status, iters, y, s, n_refine, stalled) where x is
+    Returns (x, obj, status, iters, y, s, n_refine, handover) where x is
     the primal solution (x/tau), (y, s) the tau-scaled duals — the triple
     a warm start of a nearby program feeds back in — and the last two the
-    mixed-precision telemetry (0/False under the fp64 policy).  HSDE
+    mixed-precision telemetry (0 under the fp64 policy; ``handover`` is
+    a ``HANDOVER_*`` code of :mod:`..precision`).  HSDE
     certificates make infeasibility detection residual-based: the
     embedding is always feasible and converges either to tau>0 (optimum)
     or tau->0 with kappa>0 (primal or dual infeasible).
@@ -208,11 +209,11 @@ def _hsde_ipm_core(c, b, A_mul, AT_mul, make_normal_solver,
 
     ``make_fp32_solver`` (optional) switches on the mixed policy: it maps
     ``dinv`` to an iteratively-refined fp32-factor solver with the
-    ``(w, n_refine, stalled)`` contract (:mod:`..precision`).  The kernel
-    then runs two phases — the refined fp32 factor while
+    ``(w, n_refine)`` contract (:mod:`..precision`).  The kernel then
+    runs two phases — the refined fp32 factor while
     ``mu > SWITCH_MU * mu0`` (where cond(M) is benign and the arithmetic
-    win lives), then the plain fp64 loop to certification, so the
-    stopping test is bitwise the fp64 policy's.
+    win lives) and each step lowers mu, then the plain fp64 loop to
+    certification, so the stopping test is bitwise the fp64 policy's.
     """
     n = c.shape[0]
     m = b.shape[0]
@@ -254,11 +255,11 @@ def _hsde_ipm_core(c, b, A_mul, AT_mul, make_normal_solver,
         """Body factory: one Mehrotra step with the given normal solver.
 
         ``solver_of_dinv(dinv)`` returns a solve with the
-        ``(w, n_refine, stalled)`` contract (fp64 solvers report 0/False).
+        ``(w, n_refine)`` contract (fp64 solvers report 0).
         """
 
         def body(carry):
-            x, y, s, tau, kappa, status, done, nit, nref, stall = carry
+            x, y, s, tau, kappa, status, done, nit, nref = carry
             mu = (x @ s + tau * kappa) / (n + 1)
             rP = b * tau - A_mul(x)
             rD = c * tau - AT_mul(y) - s
@@ -274,20 +275,20 @@ def _hsde_ipm_core(c, b, A_mul, AT_mul, make_normal_solver,
                 return A_mul(dinv * r)
 
             # tau-column system, shared by predictor and corrector
-            v, nr_v, st_v = solve_M(b + A_d_mul(c))
+            v, nr_v = solve_M(b + A_d_mul(c))
             xv = dinv * (AT_mul(v) - c)
             denom_v = b @ v - c @ xv + kappa / tau
 
             def direction(eta, cc, ck):
                 w = -eta * rD + cc / x
-                u, nr_u, st_u = solve_M(eta * rP - A_d_mul(w))
+                u, nr_u = solve_M(eta * rP - A_d_mul(w))
                 xu = dinv * (AT_mul(u) + w)
                 dtau = (eta * rG + ck / tau - b @ u + c @ xu) / denom_v
                 dy = u + dtau * v
                 dx = xu + dtau * xv
                 ds = (cc - s * dx) / x
                 dkappa = (ck - kappa * dtau) / tau
-                return dx, dy, ds, dtau, dkappa, nr_u, st_u
+                return dx, dy, ds, dtau, dkappa, nr_u
 
             def step_len(dx, ds, dtau, dkappa):
                 a = jnp.minimum(max_step(x, dx), max_step(s, ds))
@@ -297,7 +298,7 @@ def _hsde_ipm_core(c, b, A_mul, AT_mul, make_normal_solver,
                 return a
 
             # predictor (affine scaling)
-            dxa, dya, dsa, dta, dka, nr_a, st_a = direction(
+            dxa, dya, dsa, dta, dka, nr_a = direction(
                 1.0, -x * s, -tau * kappa)
             alpha_a = jnp.minimum(1.0, step_len(dxa, dsa, dta, dka))
             mu_aff = (((x + alpha_a * dxa) @ (s + alpha_a * dsa)
@@ -308,29 +309,31 @@ def _hsde_ipm_core(c, b, A_mul, AT_mul, make_normal_solver,
             # corrector (combined direction, same factorization)
             cc = sigma * mu - x * s - dxa * dsa
             ck = sigma * mu - tau * kappa - dta * dka
-            dx, dy, ds, dtau, dkappa, nr_c, st_c = direction(
+            dx, dy, ds, dtau, dkappa, nr_c = direction(
                 1.0 - sigma, cc, ck)
             alpha = jnp.minimum(1.0, 0.99995 * step_len(dx, ds, dtau, dkappa))
             finite = (jnp.all(jnp.isfinite(dx)) & jnp.all(jnp.isfinite(dy))
                       & jnp.all(jnp.isfinite(ds)) & jnp.isfinite(dtau)
                       & jnp.isfinite(dkappa) & jnp.isfinite(alpha))
-            alpha = jnp.where(finite & ~done, alpha, 0.0)
+            # a non-finite direction (a failed factor) leaves the iterate
+            # as it was: a zero step would still give 0 * nan = nan
+            take = finite & ~done
 
-            x = x + alpha * dx
-            y = y + alpha * dy
-            s = s + alpha * ds
-            tau = tau + alpha * dtau
-            kappa = kappa + alpha * dkappa
+            def step(v, dv):
+                return jnp.where(take, v + alpha * dv, v)
+
+            x, y, s = step(x, dx), step(y, dy), step(s, ds)
+            tau, kappa = step(tau, dtau), step(kappa, dkappa)
             status, done_now = classify(x, y, s, tau, kappa)
             return (x, y, s, tau, kappa, status, done | done_now,
-                    nit + 1, nref + nr_v + nr_a + nr_c,
-                    stall | st_v | st_a | st_c)
+                    nit + 1, nref + nr_v + nr_a + nr_c)
 
         return body
 
     status0, done0 = classify(x0, y0, s0, tau0, kappa0)
     carry0 = (x0, y0, s0, tau0, kappa0, status0, done0, jnp.asarray(0),
-              jnp.asarray(0), jnp.asarray(False))
+              jnp.asarray(0))
+    handover = jnp.asarray(_precision.HANDOVER_NONE, jnp.int32)
     if make_fp32_solver is None:
         carry = jax.lax.while_loop(
             cond, make_body(lambda d: _count0(make_normal_solver(d))),
@@ -338,29 +341,49 @@ def _hsde_ipm_core(c, b, A_mul, AT_mul, make_normal_solver,
     else:
         # phase 1: fp32 factor + fp64-residual refinement while the
         # iterates are far from the boundary (cond(M) ~ 1/mu fits fp32)
-        def cond1(carry):
-            x, _, s, tau, kappa, _, done, nit = carry[:8]
-            mu = (x @ s + tau * kappa) / (n + 1)
-            return ((~done) & (nit < max_iter)
-                    & (mu > _precision.SWITCH_MU * mu0))
+        # and each step still lowers mu.  A step that does not (a failed
+        # fp32 factor gives a non-finite direction, and the step is
+        # refused) would fail the same way at the same iterate forever,
+        # so it hands the lane to the fp64 loop and says why.
+        def mu_of(carry):
+            x, _, s, tau, kappa = carry[:5]
+            return (x @ s + tau * kappa) / (n + 1)
 
-        carry = jax.lax.while_loop(
-            cond1, make_body(make_fp32_solver), carry0)
+        def cond1(state):
+            carry, why = state
+            done, nit = carry[6], carry[7]
+            return ((~done) & (nit < max_iter)
+                    & (why == _precision.HANDOVER_NONE)
+                    & (mu_of(carry) > _precision.SWITCH_MU * mu0))
+
+        body32 = make_body(make_fp32_solver)
+
+        def body1(state):
+            carry = body32(state[0])
+            mu_old, mu_new = mu_of(state[0]), mu_of(carry)
+            why = jnp.where(
+                mu_new < mu_old, _precision.HANDOVER_NONE,
+                jnp.where(mu_new == mu_old, _precision.HANDOVER_NO_STEP,
+                          _precision.HANDOVER_NO_DESCENT))
+            return carry, why.astype(jnp.int32)
+
+        carry, handover = jax.lax.while_loop(
+            cond1, body1, (carry0, handover))
         # phase 2: plain fp64 finish — certification is exactly fp64's
         carry = jax.lax.while_loop(
             cond, make_body(lambda d: _count0(make_normal_solver(d))),
             carry)
-    x, y, s, tau, kappa, status, done, nit, nref, stall = carry
+    x, y, s, tau, kappa, status, done, nit, nref = carry
     inv_tau = 1.0 / jnp.maximum(tau, 1e-300)
     xsol = x * inv_tau
     return (xsol, c @ xsol, status, nit, y * inv_tau, s * inv_tau,
-            nref, stall)
+            nref, handover)
 
 
 def _count0(solve):
-    """Adapt a plain fp64 solve to the (w, n_refine, stalled) contract."""
+    """Adapt a plain fp64 solve to the (w, n_refine) contract."""
     def solve_M(rhs):
-        return solve(rhs), jnp.asarray(0), jnp.asarray(False)
+        return solve(rhs), jnp.asarray(0)
     return solve_M
 
 
@@ -746,12 +769,14 @@ def _banded_ops(geom: BandedGeometry, F, ext, dcoef, colix,
     ``O(m^2 nv)`` build + ``O(m^3)`` factor on the dense paths.
 
     The factor/substitution passes live in
-    :mod:`repro.kernels.dlt_banded_chol`; ``impl`` selects the pure-JAX
-    scans (``"scan"``) or the Pallas port (``"pallas"``, with
-    ``interpret`` running the kernel body uncompiled on any backend).
-    Both passes are dtype-generic: under ``precision="mixed"`` the same
-    kernels factor Jacobi-equilibrated fp32 blocks and the returned
-    fp32 solver is wrapped in fp64 iterative refinement.
+    :mod:`repro.kernels.dlt_banded_chol`.  The fp64 factor always runs
+    the pure-JAX scans; ``impl`` selects what factors the mixed
+    policy's Jacobi-equilibrated fp32 blocks: the same scans
+    (``"scan"``) or the Pallas port (``"pallas"``, with ``interpret``
+    running the kernel body uncompiled off the TPU).  The Pallas kernel
+    only ever sees fp32 operands: the TPU's kernel compiler has no
+    64-bit types.  The returned fp32 solver is wrapped in fp64
+    iterative refinement.
 
     Returns ``(A_mul, AT_mul, make_normal_solver, make_fp32_solver)``
     (the last is None under the fp64 policy).
@@ -797,7 +822,7 @@ def _banded_ops(geom: BandedGeometry, F, ext, dcoef, colix,
 
     posc = jnp.where(geom.posmat >= 0, geom.posmat, 0)
 
-    def _band_solve(C, X, V, Cb, rhs, scale=None):
+    def _band_solve(C, X, V, Cb, rhs, scale=None, impl="scan"):
         """Scatter rhs into band layout, run the substitutions, gather."""
         rs = rhs if scale is None else rhs * scale
         rband = (rs[posc] * (geom.posmat >= 0)).astype(C.dtype)  # (K, s)
@@ -822,8 +847,7 @@ def _banded_ops(geom: BandedGeometry, F, ext, dcoef, colix,
         Opad = jnp.concatenate(
             [jnp.zeros((1, s, s), dtype=rhs_dtype), Oblk[:-1]], axis=0)
 
-        C, X, V, Cb = _chol_kernels.factor(Dblk, Opad, Ublk, Db,
-                                           impl=impl, interpret=interpret)
+        C, X, V, Cb = _chol_kernels.factor(Dblk, Opad, Ublk, Db)
         return lambda rhs: _band_solve(C, X, V, Cb, rhs)
 
     def _band_mul(D64, O64, U64, Db64):
@@ -896,7 +920,8 @@ def _banded_ops(geom: BandedGeometry, F, ext, dcoef, colix,
 
             def solve32(rhs):
                 with jax.named_scope(_precision.FP32_FACTOR_SCOPE):
-                    return _band_solve(C, X, V, Cb, rhs, scale=scale)
+                    return _band_solve(C, X, V, Cb, rhs, scale=scale,
+                                       impl=impl)
 
             return _precision.refined_solver(
                 solve32, M_mul, refine_max, refine_tol)
@@ -912,9 +937,9 @@ def _hsde_ipm_banded(c, F, b, ext, dcoef, colix, Fg, Hg, Ug, Bq,
                      refine_tol: float = _precision.DEFAULT_REFINE_TOL):
     """Banded instantiation of the HSDE kernel (one lane, vmapped).
 
-    ``impl="pallas"`` swaps the factor/substitution scans for the
-    Pallas ``dlt_banded_chol`` kernel (``interpret`` runs it uncompiled
-    for backends without the native lowering).
+    ``impl="pallas"`` swaps the mixed policy's fp32 factor/substitution
+    scans for the Pallas ``dlt_banded_chol`` kernel (``interpret`` runs
+    it uncompiled for backends without the native lowering).
     """
     A_mul, AT_mul, make_solver, make_fp32 = _banded_ops(
         geom, F, ext, dcoef, colix, Fg, Hg, Ug, Bq,
@@ -966,10 +991,10 @@ def solve_lp_batch(c, A, b, max_iter: int = 25, tol: float = 1e-8):
 
     This is the generic dense entry point; :func:`batched_solve` routes
     through the structured ``[F | I]`` kernel instead.  Runs in float64
-    under a locally scoped ``enable_x64`` so the rest of the (float32)
+    under a locally scoped ``x64_scope`` so the rest of the (float32)
     model stack is unaffected.
     """
-    with jax.experimental.enable_x64():
+    with _precision.x64_scope():
         c = jnp.asarray(c, jnp.float64)
         A = jnp.asarray(A, jnp.float64)
         b = jnp.asarray(b, jnp.float64)
@@ -995,9 +1020,10 @@ def compile_cache_info() -> dict:
     """Compiled-family cache state of the shared default engine.
 
     Returns shape keys currently held by the LRU plus the engine's
-    hit/miss counters and — when ``EngineConfig.compile_cache_dir`` is
-    set — the persistent JAX compilation-cache directory and its entry
-    count.  Sessions built with their own :class:`DLTEngine` should call
+    hit/miss counters and — when the persistent cache is on (see
+    :func:`~repro.core.dlt.engine.enable_compile_cache`) — the JAX
+    compilation-cache directory and its entry count.  Sessions built
+    with their own :class:`DLTEngine` should call
     ``engine.compile_cache_info()`` instead.
     """
     from .engine import get_default_engine
@@ -1076,9 +1102,12 @@ class BatchedSolution:
 
     ``precision`` records the engine policy that produced the batch;
     under ``"mixed"``, ``refine_iterations[k]`` counts the lane's
-    iterative-refinement corrections and ``precision_fallback_mask[k]``
-    marks lanes the fp32-factor path could not certify and that were
-    re-solved with the full-fp64 executable.
+    iterative-refinement corrections, ``phase1_handover[k]`` says why
+    the lane left its fp32 phase early (a ``HANDOVER_*`` code of
+    :mod:`.precision`, 0 where it did not) and
+    ``precision_fallback_mask[k]`` marks lanes the fp32-factor path
+    could not certify and that were re-solved with the full-fp64
+    executable.
     """
 
     spec: BatchedSystemSpec
@@ -1093,6 +1122,7 @@ class BatchedSolution:
     fallback_mask: Optional[np.ndarray] = None  # (B,) bool
     precision: str = "fp64"
     refine_iterations: Optional[np.ndarray] = None  # (B,) mixed only
+    phase1_handover: Optional[np.ndarray] = None  # (B,) mixed only
     precision_fallback_mask: Optional[np.ndarray] = None  # (B,) bool
 
     @property
@@ -1183,7 +1213,7 @@ def batched_solve(
     specs,
     frontend: bool = True,
     formulation: "Formulation | str | None" = None,
-    max_iter: int = 25,
+    max_iter: int = 40,
     tol: float = 1e-8,
     verify: bool = True,
     oracle_fallback: bool = True,

@@ -15,7 +15,7 @@ shares.  The session API keeps it:
 * :class:`DLTEngine` — the whole workload surface as methods
   (``solve``, ``solve_batch``, ``sweep``, ``grid``, ``advisor``,
   ``map``) over one owned compiled-executable LRU (hit/miss counters,
-  optional on-disk persistence through the JAX compilation cache) and
+  on-disk persistence through the JAX compilation cache) and
   one stats ledger.
 * **Warm-started IPM for parametric families**: prefix/grid sweeps solve
   a strided subset of anchor lanes cold, then restart every remaining
@@ -43,6 +43,7 @@ import itertools
 import json
 import os
 import threading
+import time
 from typing import Iterable, Iterator, Optional, Sequence, Tuple, Union
 
 import functools
@@ -97,6 +98,7 @@ __all__ = [
     "EngineConfig",
     "EngineStats",
     "DLTEngine",
+    "enable_compile_cache",
     "get_default_engine",
 ]
 
@@ -194,7 +196,9 @@ class EngineConfig:
       oracle_fallback: re-solve uncertified lanes with the scalar simplex
         (recorded in ``BatchedSolution.fallback_mask`` — never silent).
       max_iter / tol: interior-point iteration budget and residual
-        tolerance.
+        tolerance.  Small bursts routed over a 4 x 128 fleet need 26-27
+        iterations from the cold start; 40 leaves room above them, and
+        lanes that converge earlier exit earlier.
       chunk_size: scenarios per device batch — also the chunk length of
         :meth:`DLTEngine.map`.
       bucket / m_bucket_edges: size-bucketed batching of ragged families.
@@ -202,17 +206,18 @@ class EngineConfig:
         ``"auto"`` picks the banded path whenever the formulation
         publishes a :class:`~repro.core.dlt.formulations.BandedStructure`
         and the family has at least ``banded_min_rows`` constraint rows
-        (falling back to ``"structured"`` otherwise; on backends with
-        the Pallas ``dlt_banded_chol`` lowering it upgrades further to
-        the Pallas tier, recording ``stats.kernel_fallbacks`` when a
-        candidate backend turns out unsupported); ``"banded"`` pins
-        the block-tridiagonal-arrowhead Cholesky scans (a ``ValueError``
-        at solve time if the formulation has no structure);
-        ``"pallas_banded"`` pins the Pallas port of those scans (a
-        ``ValueError`` on backends without the lowering unless
-        ``pallas_interpret`` is set); ``"structured"`` pins the
-        ``[F | I]`` dense-Cholesky path; ``"dense"`` runs the generic
-        dense kernel (debug / apples-to-apples baselines).
+        (falling back to ``"structured"`` otherwise); on the TPU under
+        ``precision="mixed"`` it takes the Pallas tier, whose kernel
+        then factors the fp32 phase (the TPU kernel compiler has no
+        64-bit types, so fp64 factors always run the XLA scans);
+        ``"banded"`` pins the block-tridiagonal-arrowhead Cholesky
+        scans (a ``ValueError`` at solve time if the formulation has no
+        structure); ``"pallas_banded"`` pins the Pallas port of the
+        fp32 factor (a ``ValueError`` unless ``precision="mixed"``, and
+        off the TPU unless ``pallas_interpret`` is set);
+        ``"structured"`` pins the ``[F | I]`` dense-Cholesky path;
+        ``"dense"`` runs the generic dense kernel (debug /
+        apples-to-apples baselines).
       banded_min_rows: minimum constraint-row count for ``"auto"`` to
         choose the banded kernel.  ``None`` (default) consults the
         per-backend autotune table written by
@@ -220,10 +225,10 @@ class EngineConfig:
         hard-coded 32-row break-even (a 2-core CPU measurement) when
         no table covers the current backend.
       pallas_interpret: run the Pallas kernel in interpret mode (the
-        body executes as plain jnp ops on any backend) — the testing /
-        CI-parity knob; makes ``kernel="pallas_banded"`` legal on CPU.
-        It never changes ``"auto"`` routing: interpret mode is far
-        slower than the scan kernels, so it only runs when pinned.
+        body executes as plain jnp ops) — the CPU parity knob; makes
+        ``kernel="pallas_banded"`` legal off the TPU.  It never runs on
+        the TPU and never changes ``"auto"`` routing: interpret mode is
+        far slower than the scan kernels, so it only runs when pinned.
       executor: how compiled lane batches run — ``"local"`` (one
         ``jit(vmap)`` on the default device, the classic path),
         ``"sharded"`` (``shard_map`` over a 1-D lane mesh across the
@@ -258,8 +263,8 @@ class EngineConfig:
         ``None`` (default) defers to ``$DLT_PRECISION``, falling back
         to ``"fp64"``.  The policy keys the AOT compile cache.
       refine_max: iterative-refinement correction cap per normal solve
-        under ``precision="mixed"`` (0 disables refinement — every fp32
-        solve is then flagged stalled unless it is already accurate).
+        under ``precision="mixed"`` (0 disables refinement: phase 1
+        steps along the raw fp32 directions).
       refine_tol: relative fp64-residual target of the refinement loop.
       warm_transfer: allow warm sweeps to seed a bucket's anchors from a
         neighboring ``(N, M-bucket)`` bucket's completed anchors via the
@@ -268,11 +273,9 @@ class EngineConfig:
         count and a published ``BandedStructure`` transfer; anything
         else cold-starts exactly as before.
       compile_cache_size: entries kept in the engine's AOT-compiled
-        family-shape LRU.
-      compile_cache_dir: when set, also persist compiled executables via
-        the JAX compilation cache in this directory so later *processes*
-        skip XLA compilation of known shapes.  (JAX scopes this setting
-        per process, not per engine.)
+        family-shape LRU.  Compiles persist across *processes* through
+        JAX's compilation cache, which is process-wide and placed by the
+        entry point (:func:`enable_compile_cache`), never by a session.
     """
 
     formulation: FormulationLike = None
@@ -280,7 +283,7 @@ class EngineConfig:
     engine: str = "batched"
     verify: bool = True
     oracle_fallback: bool = True
-    max_iter: int = 25
+    max_iter: int = 40
     tol: float = 1e-8
     chunk_size: int = 256
     bucket: str = "size"
@@ -300,7 +303,6 @@ class EngineConfig:
     refine_tol: float = _precision.DEFAULT_REFINE_TOL
     warm_transfer: bool = True
     compile_cache_size: int = COMPILE_CACHE_SIZE
-    compile_cache_dir: Optional[str] = None
 
     def __post_init__(self):
         object.__setattr__(self, "m_bucket_edges",
@@ -398,8 +400,8 @@ class EngineStats:
     warm_iterations: int = 0    # IPM iterations spent on warm lanes
     banded_lanes: int = 0       # lanes routed through the banded scan kernel
     pallas_lanes: int = 0       # lanes routed through the Pallas banded kernel
-    kernel_fallbacks: int = 0   # auto-routing downgrades (pallas->banded,
-                                # structureless->structured), per lane group
+    kernel_fallbacks: int = 0   # auto-routing downgrades (structureless
+                                # formulation -> structured), per lane group
     resolve_lanes: int = 0      # warm lanes re-solved at the full budget
     fallback_lanes: int = 0     # lanes re-solved by the simplex oracle
     cache_hits: int = 0         # compiled-executable LRU hits
@@ -408,10 +410,14 @@ class EngineStats:
                                 # (invariant: hits + misses == lookups)
     cache_contention: int = 0   # lookups that blocked on a peer thread's
                                 # in-flight compile of the same shape
+    compile_ms: int = 0         # wall milliseconds spent compiling misses
+                                # (summed over threads compiling at once)
     refine_iterations: int = 0  # fp64-residual refinement corrections
                                 # spent by mixed-precision solves
     precision_fallback_lanes: int = 0  # mixed lanes re-solved with the
                                 # full-fp64 executable
+    phase1_handover_lanes: int = 0  # mixed lane solves whose fp32 phase
+                                # ended on a step that did not lower mu
     transfer_lanes: int = 0     # anchors warm-seeded from a neighboring
                                 # bucket via cross-bucket dual transfer
 
@@ -473,9 +479,9 @@ class _EngineState:
             pallas_lanes=0, kernel_fallbacks=0,
             resolve_lanes=0, fallback_lanes=0,
             cache_hits=0, cache_misses=0,
-            cache_lookups=0, cache_contention=0,
+            cache_lookups=0, cache_contention=0, compile_ms=0,
             refine_iterations=0, precision_fallback_lanes=0,
-            transfer_lanes=0)
+            phase1_handover_lanes=0, transfer_lanes=0)
 
     def bump(self, **by):
         with self.counter_lock:
@@ -507,16 +513,27 @@ class _EngineState:
                 self.compiled.popitem(last=False)
 
 
-def _enable_persistent_cache(cache_dir: str) -> None:
-    """Point the process-wide JAX compilation cache at ``cache_dir``."""
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-    for opt, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                     ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(opt, val)
-        except AttributeError:  # option not present in this jax version
-            pass
+#: The variable through which JAX itself places its persistent
+#: compilation cache; where it is set, nothing here overrides it.
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
+def enable_compile_cache(default_dir: Union[str, os.PathLike]) -> str:
+    """Persist every compiled executable of this process; returns the dir.
+
+    Entry points (benchmarks, ``chip_smoke.py``) call this once before
+    their first solve.  Where ``$JAX_COMPILATION_CACHE_DIR`` is set, JAX
+    has already read it and that directory is kept; otherwise
+    ``default_dir`` is used — a fixed path in the checkout, since the
+    path is part of what a later process must find again.  Either way
+    only the size and time thresholds are lowered, so every engine
+    executable persists, however small or quick to compile.
+    """
+    if not os.environ.get(COMPILE_CACHE_ENV):
+        jax.config.update("jax_compilation_cache_dir", str(default_dir))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return jax.config.jax_compilation_cache_dir
 
 
 def _family_take(fam: FamilyLP, pos: np.ndarray) -> FamilyLP:
@@ -585,9 +602,10 @@ class DLTEngine:
             ...
 
     The engine owns the AOT-compiled family-shape LRU (shared with every
-    ``configured()`` view), counts hits/misses/fallbacks/iterations in
-    ``stats``, and — with ``compile_cache_dir`` set — persists compiled
-    executables across processes via the JAX compilation cache.
+    ``configured()`` view) and counts hits/misses/fallbacks/iterations
+    in ``stats``; compiled executables persist across processes through
+    the JAX compilation cache wherever the entry point enabled it
+    (:func:`enable_compile_cache`).
 
     **Concurrency model.**  A session (and its ``configured()`` views)
     may be driven from many threads at once.  The solve path mutates no
@@ -596,9 +614,9 @@ class DLTEngine:
     - configs (``EngineConfig``), specs, formulation capabilities and
       compile keys are frozen dataclasses / plain tuples; each call
       allocates its own batch arrays and carries;
-    - ``jax.experimental.enable_x64`` (the dtype scope every solve
-      chunk runs under) is thread-local in jax, so concurrent fp32 /
-      fp64 sessions do not leak into each other;
+    - :func:`~.precision.x64_scope` (``jax.enable_x64(True)``, the
+      dtype scope every solve chunk runs under) is thread-local in jax,
+      so concurrent fp32 / fp64 sessions do not leak into each other;
     - module-level caches on the path (`formulations`/`executors`
       registries, autotune tables) are populated at import time or via
       ``functools.lru_cache`` — both safe to read concurrently;
@@ -623,8 +641,6 @@ class DLTEngine:
         self._state = _EngineState()
         self._executor: Optional[Executor] = None
         self._exec_lock = threading.Lock()
-        if config.compile_cache_dir is not None:
-            _enable_persistent_cache(config.compile_cache_dir)
 
     # ---- configuration ---------------------------------------------------
 
@@ -642,9 +658,6 @@ class DLTEngine:
         eng._state = self._state
         eng._executor = None
         eng._exec_lock = threading.Lock()
-        if (eng.config.compile_cache_dir is not None
-                and eng.config.compile_cache_dir != self.config.compile_cache_dir):
-            _enable_persistent_cache(eng.config.compile_cache_dir)
         return eng
 
     def _formulation(self, frontend: bool,
@@ -714,7 +727,9 @@ class DLTEngine:
         (``hits + misses == lookups``; ``contention`` is lookups that
         blocked on a peer thread's in-flight compile); ``in_flight`` is
         the number of compiles currently owned by some thread and
-        ``stripes`` the latch-table stripe count.
+        ``stripes`` the latch-table stripe count.  ``persist_dir`` is the
+        process's JAX compilation-cache directory (``None`` while the
+        persistent cache is off) and ``persist_entries`` its entry count.
         """
         cfg, st = self.config, self._state
         with st.lru_lock:
@@ -734,12 +749,15 @@ class DLTEngine:
             "contention": contention,
             "in_flight": sum(len(t) for t in st.inflight),
             "stripes": len(st.stripe_locks),
-            "persist_dir": cfg.compile_cache_dir,
+            "persist_dir": None,
             "persist_entries": None,
         }
-        if cfg.compile_cache_dir and os.path.isdir(cfg.compile_cache_dir):
-            info["persist_entries"] = sum(
-                1 for _ in os.scandir(cfg.compile_cache_dir))
+        persist = (jax.config.jax_compilation_cache_dir
+                   if jax.config.jax_enable_compilation_cache else None)
+        if persist:
+            info["persist_dir"] = persist
+            if os.path.isdir(persist):
+                info["persist_entries"] = sum(1 for _ in os.scandir(persist))
         return info
 
     # ---- kernel routing + compiled executables ---------------------------
@@ -757,26 +775,20 @@ class DLTEngine:
         """The resolved numeric policy (config value or $DLT_PRECISION)."""
         return _precision.resolve_precision(self.config.precision)
 
-    def _banded_min_rows(self) -> int:
-        """Effective ``auto`` break-even: pinned, autotuned, or default."""
+    def banded_min_rows(self) -> Tuple[int, str]:
+        """Effective ``auto`` break-even and where it came from.
+
+        The source is ``"config"`` (a pinned ``banded_min_rows``), the
+        path of the autotune table that covers this backend and policy,
+        or ``"default"`` (:data:`BANDED_MIN_ROWS`).
+        """
         if self.config.banded_min_rows is not None:
-            return self.config.banded_min_rows
+            return self.config.banded_min_rows, "config"
         tuned = _autotuned_min_rows(jax.default_backend(),
                                     self._precision_policy())
-        return BANDED_MIN_ROWS if tuned is None else tuned
-
-    @staticmethod
-    def _pallas_candidate() -> bool:
-        """Should ``auto`` even consider the Pallas kernel tier here?
-
-        Only accelerator backends, where the native lowering plausibly
-        exists and pays.  ``pallas_interpret`` deliberately does NOT
-        make Pallas an auto candidate: interpret mode is a correctness
-        / parity tool orders of magnitude slower than the scans, so it
-        only runs when the kernel is PINNED (``kernel="pallas_banded"``)
-        — never routed to implicitly.
-        """
-        return jax.default_backend() in ("tpu", "gpu")
+        if tuned is None:
+            return BANDED_MIN_ROWS, "default"
+        return tuned, os.environ.get(KERNEL_AUTOTUNE_ENV, KERNEL_AUTOTUNE_PATH)
 
     def _kernel_plan(self, fm: Formulation, sub: BatchedSystemSpec,
                      fam: FamilyLP) -> _KernelPlan:
@@ -785,14 +797,18 @@ class DLTEngine:
         ``auto`` routes through the banded kernel whenever the
         formulation publishes a banded structure AND the family is big
         enough to amortize the block scan (``banded_min_rows``, which
-        consults the per-backend autotune table when left ``None``),
-        upgrading to the Pallas tier when the backend supports it —
-        a candidate backend without support falls back to the scans and
-        records ``stats.kernel_fallbacks``.  It falls back to the
-        structured dense-Cholesky path otherwise (also recorded).
-        Pinning ``kernel="banded"`` on a structureless formulation, or
-        ``kernel="pallas_banded"`` on an unsupported backend, is a
-        ``ValueError`` rather than a silent downgrade.
+        consults the per-backend autotune table when left ``None``).
+        On the TPU under ``precision="mixed"`` it takes the Pallas tier:
+        there the kernel factors the fp32 phase, the one program of the
+        kernel the chip's compiler accepts (it has no 64-bit types, so
+        the fp64 factor always runs the XLA scans), and never in
+        interpret mode.  It falls back to
+        the structured dense-Cholesky path for structureless
+        formulations (recorded in ``stats.kernel_fallbacks``) and for
+        small families.  Pinning ``kernel="banded"`` on a structureless
+        formulation, or ``kernel="pallas_banded"`` where the Pallas
+        kernel cannot run, is a ``ValueError`` rather than a silent
+        downgrade.
         """
         cfg = self.config
         kind = cfg.kernel
@@ -800,19 +816,27 @@ class DLTEngine:
         if (kind in ("auto", "banded", "pallas_banded")
                 and self._caps(fm).supports_banded):
             struct = fm.banded_structure(sub.n_max, sub.m_max)
+        mixed = self._precision_policy() == "mixed"
         if kind == "pallas_banded":
             if struct is None:
                 raise ValueError(
                     f"kernel='pallas_banded' but formulation {fm.name!r} "
                     "declares supports_banded=False — use kernel='auto' "
                     "(structured fallback) or kernel='structured'")
-            if not _chol_kernels.pallas_supported(
-                    interpret=cfg.pallas_interpret):
+            if not mixed:
                 raise ValueError(
-                    "kernel='pallas_banded' is not supported on the "
-                    f"{jax.default_backend()!r} backend — the Pallas "
-                    "dlt_banded_chol kernel lowers on TPU only; set "
-                    "pallas_interpret=True (parity testing) or use "
+                    "kernel='pallas_banded' factors the fp32 phase of "
+                    "precision='mixed' only (the TPU kernel compiler has "
+                    "no 64-bit types) — set precision='mixed' or use "
+                    "kernel='banded'")
+            backend = jax.default_backend()
+            if not _chol_kernels.pallas_supported(
+                    backend, interpret=cfg.pallas_interpret):
+                raise ValueError(
+                    f"kernel='pallas_banded' is not supported on the "
+                    f"{backend!r} backend — the Pallas dlt_banded_chol "
+                    "kernel compiles on TPU only, and pallas_interpret "
+                    "(the CPU parity knob) never runs on the chip; use "
                     "kernel='auto' / 'banded'")
         elif kind in ("auto", "banded"):
             if struct is None:
@@ -823,17 +847,12 @@ class DLTEngine:
                         "(structured fallback) or kernel='structured'")
                 self._state.bump(kernel_fallbacks=1)
                 kind = "structured"
-            elif kind == "auto" and fam.dims.n_rows < self._banded_min_rows():
+            elif (kind == "auto"
+                  and fam.dims.n_rows < self.banded_min_rows()[0]):
                 kind = "structured"
-            elif kind == "auto" and self._pallas_candidate():
-                if _chol_kernels.pallas_supported(
-                        interpret=cfg.pallas_interpret):
-                    kind = "pallas_banded"
-                else:
-                    # e.g. GPU: banded-capable family, Pallas candidate,
-                    # but no lowering — fall back to the scans, visibly
-                    self._state.bump(kernel_fallbacks=1)
-                    kind = "banded"
+            elif (kind == "auto" and mixed and not cfg.pallas_interpret
+                  and _chol_kernels.pallas_supported(jax.default_backend())):
+                kind = "pallas_banded"
             else:
                 kind = "banded"
         if kind in ("banded", "pallas_banded"):
@@ -891,6 +910,7 @@ class DLTEngine:
             st.bump(cache_hits=1, cache_contention=1)
             return latch.exe
         st.bump(cache_misses=1)
+        t0 = time.perf_counter()
         try:
             fn, in_axes, args = self._kernel_signature(plan, B, warm,
                                                        max_iter)
@@ -899,6 +919,7 @@ class DLTEngine:
             latch.exc = e
             raise
         else:
+            st.bump(compile_ms=round((time.perf_counter() - t0) * 1e3))
             latch.exe = exe
             st.cache_put(key, exe, cfg.compile_cache_size)
             return exe
@@ -994,7 +1015,7 @@ class DLTEngine:
         program :meth:`_executable` would compile at this shape —
         traced through the configured executor's
         :meth:`~.executors.Executor.wrap` inside the same
-        ``enable_x64`` scope the runtime solve uses, so the jaxpr
+        ``x64_scope`` the runtime solve uses, so the jaxpr
         dtypes match execution.  ``lowered`` is the jit Lowering when
         ``lower`` is set (``None`` otherwise); nothing is compiled
         either way.  This is the entry point the
@@ -1004,7 +1025,7 @@ class DLTEngine:
         mi = int(self.config.max_iter if max_iter is None else max_iter)
         Bp = executor.pad_batch(batch, warm)
         fn, in_axes, args = self._kernel_signature(plan, Bp, warm, mi)
-        with jax.experimental.enable_x64():
+        with _precision.x64_scope():
             closed, lowered = executor.trace(fn, in_axes, args, lower=lower)
         key = self._cache_key(plan, Bp, warm, mi, executor.cache_token())
         return closed, lowered, key
@@ -1044,9 +1065,11 @@ class DLTEngine:
         order) for seeding further warm starts.  ``max_iter`` overrides
         the config budget (the adaptive warm budget rides this).
 
-        Returns ``(x, status, iters, n_refine, stalled[, y, s])`` —
-        the last two per-lane mixed-precision telemetry (zeros/False
-        under the fp64 policy).
+        Returns ``(x, status, iters, n_refine, handover[, y, s])`` —
+        the last two per-lane mixed-precision telemetry (zeros under
+        the fp64 policy; ``handover`` holds ``HANDOVER_*`` codes, and
+        every non-zero one is counted in
+        ``stats.phase1_handover_lanes``).
         """
         cfg = self.config
         executor = self._resolve_executor()
@@ -1054,8 +1077,8 @@ class DLTEngine:
         B = fam.c.shape[0]
         warm = init is not None
         mi = int(cfg.max_iter if max_iter is None else max_iter)
-        xs, sts, nits, nrefs, stalls, ys, ss = [], [], [], [], [], [], []
-        with jax.experimental.enable_x64():
+        xs, sts, nits, nrefs, hos, ys, ss = [], [], [], [], [], [], []
+        with _precision.x64_scope():
             for lo in range(0, B, cfg.chunk_size):
                 hi = min(lo + cfg.chunk_size, B)
                 Bk = hi - lo
@@ -1087,12 +1110,14 @@ class DLTEngine:
                 jparts = [jnp.asarray(p, jnp.float64) for p in parts]
                 if plan.kind in ("banded", "pallas_banded"):
                     jparts.insert(5, jnp.asarray(plan.bfam.colix))
-                x, _, st, ni, y, s, nref, stall = exe(*jparts)
+                x, _, st, ni, y, s, nref, ho = exe(*jparts)
                 xs.append(np.asarray(x)[:Bk])
                 sts.append(np.asarray(st)[:Bk])
                 nits.append(np.asarray(ni)[:Bk])
                 nrefs.append(np.asarray(nref)[:Bk])
-                stalls.append(np.asarray(stall)[:Bk])
+                hos.append(np.asarray(ho)[:Bk])
+                self._state.bump(
+                    phase1_handover_lanes=np.count_nonzero(hos[-1]))
                 if want_state:
                     yk = np.asarray(y)[:Bk]
                     if plan.kind in ("banded", "pallas_banded"):
@@ -1100,7 +1125,7 @@ class DLTEngine:
                     ys.append(yk)
                     ss.append(np.asarray(s)[:Bk])
         out = (np.concatenate(xs), np.concatenate(sts), np.concatenate(nits),
-               np.concatenate(nrefs), np.concatenate(stalls))
+               np.concatenate(nrefs), np.concatenate(hos))
         if want_state:
             return out + (np.concatenate(ys), np.concatenate(ss))
         return out
@@ -1279,11 +1304,11 @@ class DLTEngine:
 
     def _precision_fallback(self, plan: _KernelPlan, x: np.ndarray,
                             st: np.ndarray, ni: np.ndarray,
-                            nref: np.ndarray):
+                            nref: np.ndarray, ho: np.ndarray):
         """Full-fp64 re-factor of lanes the mixed path could not certify.
 
         The mixed policy's safety net: any budget-exhausted lane (a
-        stalled refinement shows up here as non-convergence) re-runs
+        phase 1 that wasted the budget shows up here) re-runs
         cold through the fp64 executable of the same plan — surfaced in
         ``stats.precision_fallback_lanes``, never silent.  Infeasibility
         verdicts are not re-run: the mixed kernel's certification phase
@@ -1293,7 +1318,7 @@ class DLTEngine:
         pfb = np.zeros(st.shape[0], dtype=bool)
         self._state.bump(refine_iterations=nref.sum())
         if self._precision_policy() != "mixed":
-            return x, st, ni, nref, pfb
+            return x, st, ni, nref, ho, pfb
         failed = np.flatnonzero(st == STATUS_MAXITER)
         if failed.size:
             xf, stf, nif, _, _ = self.configured(
@@ -1303,7 +1328,7 @@ class DLTEngine:
             pfb[failed] = True
             self._state.bump(precision_fallback_lanes=failed.size,
                              cold_iterations=nif.sum())
-        return x, st, ni, nref, pfb
+        return x, st, ni, nref, ho, pfb
 
     def _solve_group(self, fm: Formulation, sub: BatchedSystemSpec,
                      fam: FamilyLP, warm: bool,
@@ -1333,10 +1358,11 @@ class DLTEngine:
         it.  Collecting state never changes the compiled program or the
         results, only what is copied back off-device.
 
-        Returns ``(x, st, ni, nref, pfb, carry)``: per-lane solutions,
-        statuses, iterations, refinement counts, the mixed-precision
-        fallback mask and (when collected) the anchor carry for the
-        next bucket / window.
+        Returns ``(x, st, ni, nref, ho, pfb, carry)``: per-lane
+        solutions, statuses, iterations, refinement counts, phase-1
+        handover codes (the largest of the lane's solves), the
+        mixed-precision fallback mask and (when collected) the anchor
+        carry for the next bucket / window.
         """
         st8 = self._state
         cfg = self.config
@@ -1357,7 +1383,7 @@ class DLTEngine:
                      if warm and transfer is not None else None)
             out = self._solve_family(plan, init=init0,
                                      want_state=want_carry)
-            x, st, ni, nref = out[0], out[1], out[2], out[3]
+            x, st, ni, nref, ho = out[:5]
             y = out[5] if want_carry else None
             if init0 is not None:
                 st8.bump(transfer_lanes=B, warm_lanes=B,
@@ -1370,6 +1396,7 @@ class DLTEngine:
                     x[failed], st[failed] = fout[0], fout[1]
                     ni[failed] += fout[2]
                     nref[failed] += fout[3]
+                    ho[failed] = np.maximum(ho[failed], fout[4])
                     if want_carry:
                         y[failed] = fout[5]
                     st8.bump(resolve_lanes=failed.size,
@@ -1381,14 +1408,15 @@ class DLTEngine:
             if want_carry:
                 carry = self._make_carry(fm, sub, fam, plan, np.arange(B),
                                          x, y, st, ni)
-            return self._precision_fallback(plan, x, st, ni, nref) + (carry,)
+            return (self._precision_fallback(plan, x, st, ni, nref, ho)
+                    + (carry,))
 
         anchor = np.arange(0, B, cfg.warm_stride)
         rest = np.setdiff1d(np.arange(B), anchor)
         anchor_plan = _plan_take(plan, anchor)
         init_a = (None if transfer is None
                   else self._transfer_init(fm, sub, fam, anchor, transfer))
-        xa, sta, nia, nra, _, ya, sa = self._solve_family(
+        xa, sta, nia, nra, hoa, ya, sa = self._solve_family(
             anchor_plan, init=init_a, want_state=True)
         if init_a is not None:
             st8.bump(transfer_lanes=anchor.size, warm_lanes=anchor.size,
@@ -1398,12 +1426,13 @@ class DLTEngine:
             # cold at the full budget
             failed = np.flatnonzero(sta != STATUS_OPTIMAL)
             if failed.size:
-                xf, stf, nif, nrf, _, yf, sf = self._solve_family(
+                xf, stf, nif, nrf, hof, yf, sf = self._solve_family(
                     _plan_take(anchor_plan, failed), want_state=True)
                 xa[failed], sta[failed] = xf, stf
                 ya[failed], sa[failed] = yf, sf
                 nia[failed] += nif
                 nra[failed] += nrf
+                hoa[failed] = np.maximum(hoa[failed], hof)
                 st8.bump(resolve_lanes=failed.size,
                          cold_iterations=nif.sum())
         else:
@@ -1420,8 +1449,8 @@ class DLTEngine:
         init = self._warm_init(fm, sub, fam, rest, anchor, src, xa, ya, sta)
         budget = self._warm_budget(nia, sta)
         rest_plan = _plan_take(plan, rest)
-        xr, str_, nir, nrr, _ = self._solve_family(rest_plan, init=init,
-                                                   max_iter=budget)
+        xr, str_, nir, nrr, hor = self._solve_family(rest_plan, init=init,
+                                                     max_iter=budget)
         st8.bump(warm_iterations=nir.sum())
         if budget < cfg.max_iter:
             # adaptive-budget safety net: lanes the reduced budget could
@@ -1429,21 +1458,25 @@ class DLTEngine:
             # than letting every straggler gate the whole warm chunk)
             failed = np.flatnonzero(str_ == STATUS_MAXITER)
             if failed.size:
-                xf, stf, nif, nrf, _ = self._solve_family(
+                xf, stf, nif, nrf, hof = self._solve_family(
                     _plan_take(rest_plan, failed))
                 xr[failed], str_[failed] = xf, stf
                 nir[failed] += nif
                 nrr[failed] += nrf
+                hor[failed] = np.maximum(hor[failed], hof)
                 st8.bump(resolve_lanes=failed.size,
                          cold_iterations=nif.sum())
         x = np.empty_like(fam.c)
         st = np.empty(B, dtype=sta.dtype)
         ni = np.empty(B, dtype=nia.dtype)
         nref = np.empty(B, dtype=nra.dtype)
+        ho = np.empty(B, dtype=hoa.dtype)
         x[anchor], st[anchor], ni[anchor], nref[anchor] = xa, sta, nia, nra
         x[rest], st[rest], ni[rest], nref[rest] = xr, str_, nir, nrr
+        ho[anchor], ho[rest] = hoa, hor
         st8.bump(lanes=B, warm_lanes=rest.size)
-        return self._precision_fallback(plan, x, st, ni, nref) + (carry,)
+        return (self._precision_fallback(plan, x, st, ni, nref, ho)
+                + (carry,))
 
     def _solve_batch_scalar(self, bspec: BatchedSystemSpec, frontend: bool,
                             formulation: FormulationLike) -> BatchedSolution:
@@ -1598,6 +1631,7 @@ class DLTEngine:
         iters = np.zeros(B, dtype=np.int64)
         prec = self._precision_policy()
         refits = np.zeros(B, dtype=np.int64)
+        handover = np.zeros(B, dtype=np.int32)
         pfb_all = np.zeros(B, dtype=bool)
 
         m_edges = WARM_M_BUCKET_EDGES if warm else cfg.m_bucket_edges
@@ -1623,7 +1657,7 @@ class DLTEngine:
             fam = build_family_lp(sub, fm)
             transfer = (carry_by_nb.get(ckey)
                         if warm and cfg.warm_transfer else None)
-            x, st, ni, nref, pfb, carry = self._solve_group(
+            x, st, ni, nref, ho, pfb, carry = self._solve_group(
                 fm, sub, fam, warm, transfer=transfer,
                 want_carry=want_carry)
             if carry is not None:
@@ -1643,6 +1677,7 @@ class DLTEngine:
             status[idx] = st
             iters[idx] = ni
             refits[idx] = nref
+            handover[idx] = ho
             pfb_all[idx] = pfb
 
         # exact zeros on padding of lanes no group wrote (defensive)
@@ -1709,6 +1744,7 @@ class DLTEngine:
             formulation=fm.name, fallback_mask=fallback_mask,
             precision=prec,
             refine_iterations=refits if prec == "mixed" else None,
+            phase1_handover=handover if prec == "mixed" else None,
             precision_fallback_mask=pfb_all if prec == "mixed" else None,
         ), carry_by_nb)
 

@@ -129,7 +129,8 @@ class Executor:
         optimization HLO, retrievable as text via
         ``lowered.compiler_ir("hlo")``).  Nothing is compiled or run.
         Callers own the dtype scope: trace inside
-        ``jax.experimental.enable_x64()`` when the runtime does.
+        :func:`repro.core.dlt.precision.x64_scope` when the runtime
+        does.
         """
         wrapped = self.wrap(fn, in_axes, args)
         closed = jax.make_jaxpr(wrapped)(*args)

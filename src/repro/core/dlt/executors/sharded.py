@@ -21,9 +21,13 @@ spans the largest device count that divides ``G`` — tiny chunks simply
 use fewer devices instead of padding 8x, and a 3-lane bucket runs on
 one device exactly like the local path.
 
-The ``check_rep``/``check_vma`` kwarg shim is reused from
-:mod:`repro.distributed.pipeline_parallel`, which already version-gates
-the rename across JAX releases.
+The lane program lowers through XLA's GSPMD partitioner rather than
+Shardy, JAX's default: the TPU compiler refuses Shardy's export of a
+manual computation in which an fp64 operand (an emulated f32 pair on
+the TPU) reaches a Cholesky factorization ("A tuple parameter that is
+being flattened shouldn't have frontend attributes"), and every IPM
+kernel factors fp64 normal equations.  The switch is JAX's own
+thread-local config state, scoped to this executor's compiles.
 """
 
 from __future__ import annotations
@@ -32,9 +36,10 @@ from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax import shard_map
+from jax._src.config import use_shardy_partitioner
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ....distributed.pipeline_parallel import _CHECK_KWARG, shard_map
 from .base import Executor, LANE_MICROBATCH, microbatched
 
 __all__ = ["ShardedExecutor"]
@@ -89,7 +94,7 @@ class ShardedExecutor(Executor):
             mesh=mesh,
             in_specs=specs,
             out_specs=P(self.AXIS),
-            **{_CHECK_KWARG: False},
+            check_vma=False,
         )
         shardings = tuple(NamedSharding(mesh, s) for s in specs)
         return mapped, shardings, NamedSharding(mesh, P(self.AXIS))
@@ -102,9 +107,10 @@ class ShardedExecutor(Executor):
                 args: Sequence[jax.ShapeDtypeStruct]) -> Callable:
         mapped, shardings, out_sharding = self._mapped(
             fn, in_axes, args[0].shape[0])
-        exe = (jax.jit(mapped, in_shardings=shardings,
-                       out_shardings=out_sharding)
-               .lower(*args).compile())
+        with use_shardy_partitioner(False):
+            exe = (jax.jit(mapped, in_shardings=shardings,
+                           out_shardings=out_sharding)
+                   .lower(*args).compile())
 
         def call(*arrays):
             # commit each operand to its lane sharding up front: batch

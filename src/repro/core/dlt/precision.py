@@ -20,9 +20,11 @@ the structured path: >half the batch).  The mixed policy therefore runs
 2. a plain fp64 while_loop finishes to tolerance, so convergence and
    certification are identical to the fp64 policy.
 
-Lanes whose refinement stalls in phase 1 are flagged (``stalled``) and,
-if they still fail to certify, re-solved with a full-fp64 executable by
-the engine (``stats.precision_fallback_lanes``).
+A lane whose phase-1 step does not lower mu leaves phase 1 early (a
+``HANDOVER_*`` code per lane, counted in
+``stats.phase1_handover_lanes``); a lane that still fails to certify is
+re-solved with a full-fp64 executable by the engine
+(``stats.precision_fallback_lanes``).
 
 Everything fp32 is wrapped in ``jax.named_scope(FP32_FACTOR_SCOPE)`` so
 dltlint's DL002 truncation rule can allowlist intentional casts, and the
@@ -56,9 +58,11 @@ REFINE_RESIDUAL_SCOPE = "dlt_refine_residual"
 #: over.  Relative to the lane's own initial mu so warm restarts behave.
 SWITCH_MU = 1e-5
 
-#: a refinement loop that ends with relative residual above
-#: STALL_FACTOR * refine_tol is counted as stalled.
-STALL_FACTOR = 1e3
+#: Why a mixed lane left phase 1 before mu reached the switch point:
+#: it did not (NONE); its step was refused or had zero length, which is
+#: what a non-finite fp32 direction gives (NO_STEP); or a finite step
+#: did not lower mu (NO_DESCENT).
+HANDOVER_NONE, HANDOVER_NO_STEP, HANDOVER_NO_DESCENT = 0, 1, 2
 
 #: diagonal ridge added to the *equilibrated* fp32 normal matrix
 #: (unit diagonal after Jacobi scaling, so this is a relative shift a
@@ -73,6 +77,16 @@ DEFAULT_REFINE_MAX = 4
 #: solve + an fp64 matvec — 1e-6 keeps ~1 refinement per solve on the
 #: bench family versus ~2 at 1e-9, at identical final parity.
 DEFAULT_REFINE_TOL = 1e-6
+
+
+def x64_scope():
+    """The float64 dtype scope every batched solve runs under.
+
+    ``jax.enable_x64(True)`` used as a context manager: the switch is
+    thread-local, so concurrent sessions never leak fp64 into each
+    other or into float32 code outside the scope.
+    """
+    return jax.enable_x64(True)
 
 
 def resolve_precision(precision: Optional[str]) -> str:
@@ -116,17 +130,6 @@ def fp32_cholesky(M64: jnp.ndarray) -> Callable[[jnp.ndarray], jnp.ndarray]:
     return solve32
 
 
-def plain_solver(
-    solve: Callable[[jnp.ndarray], jnp.ndarray],
-) -> Callable[[jnp.ndarray], tuple]:
-    """Adapt a plain fp64 solve to the (w, n_refine, stalled) contract."""
-
-    def solve_M(rhs):
-        return solve(rhs), jnp.asarray(0), jnp.asarray(False)
-
-    return solve_M
-
-
 def refined_solver(
     solve32: Callable[[jnp.ndarray], jnp.ndarray],
     M_mul: Callable[[jnp.ndarray], jnp.ndarray],
@@ -137,10 +140,10 @@ def refined_solver(
 
     ``solve32`` maps an fp64 rhs to an fp64-typed correction via the
     fp32 factor; ``M_mul`` is the *exact* fp64 normal-equations
-    operator.  Returns ``solve_M(rhs) -> (w, n_refine, stalled)``:
-    corrections are only accepted while they shrink the fp64 residual,
-    so a failed fp32 factor (NaN) degrades to a flagged stall instead
-    of poisoning the direction.
+    operator.  Returns ``solve_M(rhs) -> (w, n_refine)``: corrections
+    are only accepted while they shrink the fp64 residual.  A failed
+    fp32 factor (NaN) gives a non-finite ``w``, which the IPM refuses to
+    step along.
     """
     refine_max = int(refine_max)
     refine_tol = float(refine_tol)
@@ -171,11 +174,9 @@ def refined_solver(
                 jnp.where(better, rn2, rn),
             )
 
-        it, w, _, rn = jax.lax.while_loop(
+        it, w, _, _ = jax.lax.while_loop(
             cond, body, (jnp.asarray(0), w, r, rn)
         )
-        # NaN-safe: ~(rn <= bound) is True when rn is NaN.
-        stalled = ~(rn <= STALL_FACTOR * refine_tol * nrm)
-        return w, it, stalled
+        return w, it
 
     return solve_M

@@ -13,25 +13,12 @@ apply function f(stage_params, x) -> x.
 
 from __future__ import annotations
 
-import inspect
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-# The "don't check replication" kwarg was renamed check_rep -> check_vma
-# across JAX releases; pick whichever this JAX spells.
-_CHECK_KWARG = (
-    "check_vma"
-    if "check_vma" in inspect.signature(shard_map).parameters
-    else "check_rep"
-)
 
 __all__ = ["pipeline_apply", "gpipe_utilization"]
 
@@ -103,5 +90,5 @@ def pipeline_apply(
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        **{_CHECK_KWARG: False},
+        check_vma=False,
     )(stage_params, x)

@@ -34,6 +34,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -64,8 +65,34 @@ def vmem_estimate(s: int, p: int, itemsize: int = 8) -> int:
     return (2 * blocks + scratch) * itemsize
 
 
+#: int32 zero for block index maps: a Python ``0`` would trace as int64
+#: under ``x64_scope``, which the TPU kernel compiler refuses.
+_0 = np.int32(0)
+
+
 def _iota2(shape, axis):
     return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _loop(s, step, init):
+    """``fori_loop`` over ``0..s-1`` with an int32 counter.
+
+    The engine traces under ``x64_scope``, where a Python-int bound
+    would make the counter int64 — a type the TPU kernel compiler does
+    not have.
+    """
+    return jax.lax.fori_loop(jnp.int32(0), jnp.int32(s), step, init)
+
+
+def _mm(a, b):
+    """``a @ b`` at full fp32 precision.
+
+    On the TPU an fp32 matrix product otherwise runs in a single
+    bfloat16 pass, which the mixed policy's refinement loop could not
+    make up for: it is written for an fp32 factor.
+    """
+    return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=a.dtype)
 
 
 def _eye(s, dt):
@@ -88,7 +115,7 @@ def _chol(A):
         L = jnp.where(cols == j, l, L)
         return A, L
 
-    return jax.lax.fori_loop(0, s, step, (A, jnp.zeros_like(A)))[1]
+    return _loop(s, step, (A, jnp.zeros_like(A)))[1]
 
 
 def _trisolve_lower(L, B):
@@ -105,10 +132,10 @@ def _trisolve_lower(L, B):
         Bj = jnp.sum(jnp.where(rows_b == j, B, 0.0), axis=0,
                      keepdims=True)                         # (1, r)
         # Z rows >= j are still zero, so Lrow @ Z covers exactly k < j
-        zj = (Bj - Lrow @ Z) / ljj
+        zj = (Bj - _mm(Lrow, Z)) / ljj
         return jnp.where(rows_b == j, zj, Z)
 
-    return jax.lax.fori_loop(0, s, step, jnp.zeros_like(B))
+    return _loop(s, step, jnp.zeros_like(B))
 
 
 def _trisolve_lower_t(L, B):
@@ -126,10 +153,10 @@ def _trisolve_lower_t(L, B):
         Bj = jnp.sum(jnp.where(rows_b == j, B, 0.0), axis=0,
                      keepdims=True)
         # W rows <= j are still zero and L[k, j] = 0 for k < j
-        wj = (Bj - Lcol.T @ W) / ljj
+        wj = (Bj - _mm(Lcol.T, W)) / ljj
         return jnp.where(rows_b == j, wj, W)
 
-    return jax.lax.fori_loop(0, s, step, jnp.zeros_like(B))
+    return _loop(s, step, jnp.zeros_like(B))
 
 
 # ---------------------------------------------------------------------------
@@ -149,9 +176,9 @@ def _factor_kernel(D_ref, O_ref, U_ref, C_ref, X_ref, V_ref, S_ref,
 
     Dk, Okp, Uk = D_ref[0], O_ref[0], U_ref[0]
     Xk = _trisolve_lower(c_scr[...], Okp.T).T
-    Ck = _chol(Dk - Xk @ Xk.T)
-    Vk = _trisolve_lower(Ck, (Uk - v_scr[...] @ Xk.T).T).T
-    Sk = s_scr[...] + Vk @ Vk.T
+    Ck = _chol(Dk - _mm(Xk, Xk.T))
+    Vk = _trisolve_lower(Ck, (Uk - _mm(v_scr[...], Xk.T)).T).T
+    Sk = s_scr[...] + _mm(Vk, Vk.T)
     C_ref[0], X_ref[0], V_ref[0] = Ck, Xk, Vk
     c_scr[...], v_scr[...], s_scr[...] = Ck, Vk, Sk
 
@@ -165,14 +192,14 @@ def banded_factor_pallas(Dblk, Opad, Ublk, *, interpret: bool = False):
     K, s, _ = Dblk.shape
     p = Ublk.shape[1]
     dt = Dblk.dtype
-    blk_ss = pl.BlockSpec((1, s, s), lambda k: (k, 0, 0))
-    blk_ps = pl.BlockSpec((1, p, s), lambda k: (k, 0, 0))
+    blk_ss = pl.BlockSpec((1, s, s), lambda k: (k, _0, _0))
+    blk_ps = pl.BlockSpec((1, p, s), lambda k: (k, _0, _0))
     return pl.pallas_call(
         functools.partial(_factor_kernel, nblocks=K),
         grid=(K,),
         in_specs=[blk_ss, blk_ss, blk_ps],
         out_specs=[blk_ss, blk_ss, blk_ps,
-                   pl.BlockSpec((p, p), lambda k: (0, 0))],
+                   pl.BlockSpec((p, p), lambda k: (_0, _0))],
         out_shape=[
             jax.ShapeDtypeStruct((K, s, s), dt),
             jax.ShapeDtypeStruct((K, s, s), dt),
@@ -196,26 +223,27 @@ def _fwd_kernel(C_ref, X_ref, r_ref, u_ref, u_scr):
     def _init():
         u_scr[...] = jnp.zeros(u_scr.shape, C_ref.dtype)
 
-    rhs = r_ref[...].T - X_ref[0] @ u_scr[...]              # (s, 1)
+    rhs = r_ref[0].T - _mm(X_ref[0], u_scr[...])            # (s, 1)
     u = _trisolve_lower(C_ref[0], rhs)
-    u_ref[...] = u.T
+    u_ref[0] = u.T
     u_scr[...] = u
 
 
 def banded_solve_fwd_pallas(C, X, rband, *, interpret: bool = False):
     """Pallas counterpart of :func:`..ref.banded_solve_fwd` (one lane)."""
     K, s, _ = C.shape
-    blk_ss = pl.BlockSpec((1, s, s), lambda k: (k, 0, 0))
-    blk_s = pl.BlockSpec((1, s), lambda k: (k, 0))
-    return pl.pallas_call(
+    blk_ss = pl.BlockSpec((1, s, s), lambda k: (k, _0, _0))
+    blk_s = pl.BlockSpec((1, 1, s), lambda k: (k, _0, _0))
+    u = pl.pallas_call(
         _fwd_kernel,
         grid=(K,),
         in_specs=[blk_ss, blk_ss, blk_s],
         out_specs=blk_s,
-        out_shape=jax.ShapeDtypeStruct((K, s), C.dtype),
+        out_shape=jax.ShapeDtypeStruct((K, 1, s), C.dtype),
         scratch_shapes=[pltpu.VMEM((s, 1), C.dtype)],
         interpret=interpret,
-    )(C, X, rband)
+    )(C, X, rband[:, None, :])
+    return u[:, 0, :]
 
 
 def _bwd_kernel(C_ref, Xn_ref, V_ref, u_ref, wb_ref, w_ref, w_scr):
@@ -225,10 +253,10 @@ def _bwd_kernel(C_ref, Xn_ref, V_ref, u_ref, wb_ref, w_ref, w_scr):
     def _init():
         w_scr[...] = jnp.zeros(w_scr.shape, C_ref.dtype)
 
-    rhs = (u_ref[...].T - Xn_ref[0].T @ w_scr[...]
-           - V_ref[0].T @ wb_ref[...])                      # (s, 1)
+    rhs = (u_ref[0].T - _mm(Xn_ref[0].T, w_scr[...])
+           - _mm(V_ref[0].T, wb_ref[...]))                  # (s, 1)
     w = _trisolve_lower_t(C_ref[0], rhs)
-    w_ref[...] = w.T
+    w_ref[0] = w.T
     w_scr[...] = w
 
 
@@ -241,16 +269,17 @@ def banded_solve_bwd_pallas(C, Xnext, V, u, wb, *, interpret: bool = False):
     """
     K, s, _ = C.shape
     p = V.shape[1]
-    rev_ss = pl.BlockSpec((1, s, s), lambda i: (K - 1 - i, 0, 0))
-    rev_ps = pl.BlockSpec((1, p, s), lambda i: (K - 1 - i, 0, 0))
-    rev_s = pl.BlockSpec((1, s), lambda i: (K - 1 - i, 0))
-    return pl.pallas_call(
+    rev_ss = pl.BlockSpec((1, s, s), lambda i: (K - 1 - i, _0, _0))
+    rev_ps = pl.BlockSpec((1, p, s), lambda i: (K - 1 - i, _0, _0))
+    rev_s = pl.BlockSpec((1, 1, s), lambda i: (K - 1 - i, _0, _0))
+    w = pl.pallas_call(
         _bwd_kernel,
         grid=(K,),
         in_specs=[rev_ss, rev_ss, rev_ps, rev_s,
-                  pl.BlockSpec((p, 1), lambda i: (0, 0))],
+                  pl.BlockSpec((p, 1), lambda i: (_0, _0))],
         out_specs=rev_s,
-        out_shape=jax.ShapeDtypeStruct((K, s), C.dtype),
+        out_shape=jax.ShapeDtypeStruct((K, 1, s), C.dtype),
         scratch_shapes=[pltpu.VMEM((s, 1), C.dtype)],
         interpret=interpret,
-    )(C, Xnext, V, u, wb[:, None])
+    )(C, Xnext, V, u[:, None, :], wb[:, None])
+    return w[:, 0, :]

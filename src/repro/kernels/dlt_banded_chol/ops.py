@@ -4,9 +4,9 @@ One call surface, two implementations:
 
 * ``impl="scan"``   — the pure-JAX :mod:`.ref` scans (every backend; the
   parity oracle);
-* ``impl="pallas"`` — the :mod:`.kernel` Pallas port (TPU natively, any
-  backend with ``interpret=True`` — which is how CI exercises parity on
-  CPU).
+* ``impl="pallas"`` — the :mod:`.kernel` Pallas port (TPU natively,
+  compiled for fp32 operands only; CPU with ``interpret=True``, which is
+  how the tests check parity).
 
 The functions are thin and **not** jitted: the batched IPM calls them
 inside its own jitted, vmapped body.  :func:`pallas_supported` is the
@@ -37,15 +37,14 @@ def pallas_supported(backend: "str | None" = None,
                      interpret: bool = False) -> bool:
     """Can the Pallas implementation run here?
 
-    Interpret mode runs everywhere (it executes the kernel body with
-    plain jnp semantics); compiled Pallas needs the TPU lowering this
-    kernel is written against.
+    Compiled Pallas needs the TPU lowering this kernel is written
+    against.  Interpret mode (the kernel body as plain jnp ops) is the
+    CPU parity tool and never runs on the TPU, where it would stand in
+    for the compiled kernel at a fraction of its speed.
     """
-    if interpret:
-        return True
     if backend is None:
         backend = jax.default_backend()
-    return backend == "tpu"
+    return (backend != "tpu") if interpret else (backend == "tpu")
 
 
 def _check_impl(impl: str) -> None:

@@ -32,6 +32,7 @@ thread for real Poisson traffic (what the bench drives).
 from __future__ import annotations
 
 import dataclasses
+import logging
 import threading
 import time
 from concurrent.futures import Future
@@ -51,6 +52,8 @@ from .stats import _LATENCY_RESERVOIR, ServiceStats
 __all__ = ["ServiceConfig", "RouteDecision", "RouterService"]
 
 _WARM_POLICIES = ("transfer", "cold")
+
+_log = logging.getLogger(__name__)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -281,10 +284,22 @@ class RouterService:
         # on this window when several loops share the session
         with self._engine.counter_scope() as deltas:
             t0 = time.perf_counter()
-            sol, carry = self._solver.solve_batch_carry(
-                specs + [specs[-1]] * pad, frontend=self.config.frontend,
-                presorted=True, warm=warm,
-                carry_in=self._carry if warm else None)
+            try:
+                sol, carry = self._solver.solve_batch_carry(
+                    specs + [specs[-1]] * pad, frontend=self.config.frontend,
+                    presorted=True, warm=warm,
+                    carry_in=self._carry if warm else None)
+            except Exception as exc:
+                # the window's futures carry the real error (a compile or
+                # solve failure), and the loop lives on to take the next
+                # window; a probe window has no futures, so its error is
+                # logged, and it keeps the previous carry
+                for it in items:
+                    it.future.set_exception(exc)
+                self._ledger.bump(failed_decisions=len(items))
+                if not items:
+                    _log.warning("drift probe window failed", exc_info=exc)
+                return
             dt = time.perf_counter() - t0
         self._carry = carry if carry else self._carry
         self._last_counts = counts

@@ -35,7 +35,8 @@ class ServiceStatsSnapshot:
     cold_windows: int           # windows solved from the cold start point
     warm_windows: int           # drift windows warm-seeded from an anchor
     decisions: int              # futures resolved with a RouteDecision
-    failed_decisions: int       # futures failed by strict-lane errors
+    failed_decisions: int       # futures failed (strict-lane errors or
+                                # a window solve that raised)
     drift_events: int           # times the EWMA crossed the threshold
     transfer_lanes: int         # engine lanes seeded via warm_transfer
     resolve_lanes: int          # warm lanes the engine re-solved cold

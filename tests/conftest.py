@@ -23,3 +23,13 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 # repo root) must not leak into them.  Tests that exercise the table set
 # DLT_KERNEL_AUTOTUNE themselves.
 os.environ.setdefault("DLT_KERNEL_AUTOTUNE", os.devnull)
+
+# With hypothesis installed, no example database is written into the
+# checkout.
+try:
+    from hypothesis import settings as _hypothesis_settings
+except ImportError:
+    pass
+else:
+    _hypothesis_settings.register_profile("repro", database=None)
+    _hypothesis_settings.load_profile("repro")

@@ -28,11 +28,8 @@ def test_compressed_psum_matches_plain():
     print(_run("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
+from jax import shard_map
 from repro.distributed.grad_compression import compressed_psum
-try:
-    shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map
 mesh = jax.make_mesh((8,), ("data",))
 x = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
 

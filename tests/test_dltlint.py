@@ -35,6 +35,7 @@ from repro.core.dlt.formulations import get_formulation
 from repro.core.dlt.precision import (
     FP32_FACTOR_SCOPE,
     REFINE_RESIDUAL_SCOPE,
+    x64_scope,
 )
 from repro.kernels.dlt_banded_chol.kernel import (
     banded_factor_pallas,
@@ -46,7 +47,7 @@ def _artifact(fn, *args, executor="local", max_iter=25, hlo_text=None,
               x64=True, precision="fp64"):
     """TraceArtifact for a hand-written function (seeded-defect harness)."""
     import contextlib
-    ctx = jax.experimental.enable_x64() if x64 else contextlib.nullcontext()
+    ctx = x64_scope() if x64 else contextlib.nullcontext()
     with ctx:
         closed = jax.make_jaxpr(fn)(*args)
     return TraceArtifact(

@@ -3,6 +3,7 @@ sweeps, strict schedule mode, streaming map, compiled-cache counters, and
 the free-function compatibility shims."""
 
 
+import jax
 import numpy as np
 import pytest
 
@@ -16,10 +17,12 @@ from repro.core.dlt import (
     SystemSpec,
     batched_solve,
     compile_cache_info,
+    enable_compile_cache,
     get_default_engine,
     solve,
     sweep_processors,
 )
+from repro.core.dlt.engine import COMPILE_CACHE_ENV
 from repro.core.dlt.speedup import speedup_grid
 from repro.core.dlt.stacking import BatchedSystemSpec
 
@@ -45,8 +48,8 @@ def _sec6_spec(n=2, m=16, cost=False):
 def test_config_defaults_and_replace():
     cfg = EngineConfig()
     assert cfg.engine == "batched" and cfg.solver == "auto"
-    cfg2 = cfg.replace(max_iter=40, engine="scalar", solver="simplex")
-    assert cfg2.max_iter == 40 and cfg.max_iter == 25  # original untouched
+    cfg2 = cfg.replace(max_iter=60, engine="scalar", solver="simplex")
+    assert cfg2.max_iter == 60 and cfg.max_iter == 40  # original untouched
     assert isinstance(cfg2.m_bucket_edges, tuple)
 
 
@@ -259,14 +262,41 @@ def test_compile_cache_lru_eviction():
     assert info["size"] == 1 and info["maxsize"] == 1
 
 
-def test_persistent_cache_dir_is_created_and_reported(tmp_path):
+@pytest.fixture
+def restore_cache_config():
+    """Undo enable_compile_cache's process-wide JAX settings."""
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    saved = {n: getattr(jax.config, n) for n in names}
+    yield
+    for n, v in saved.items():
+        jax.config.update(n, v)
+
+
+def test_persistent_cache_dir_is_created_and_reported(
+        tmp_path, monkeypatch, restore_cache_config):
     cache_dir = tmp_path / "xla-cache"
-    eng = DLTEngine(compile_cache_dir=str(cache_dir))
+    monkeypatch.delenv(COMPILE_CACHE_ENV, raising=False)
+    assert enable_compile_cache(cache_dir) == str(cache_dir)
+    eng = DLTEngine()
     eng.solve_batch([GOOD_SPEC], frontend=True)
     info = eng.compile_cache_info()
     assert info["persist_dir"] == str(cache_dir)
     assert cache_dir.is_dir()
-    assert info["persist_entries"] is not None
+    assert info["persist_entries"] >= 1
+
+
+def test_compile_cache_env_dir_is_never_overridden(
+        tmp_path, monkeypatch, restore_cache_config):
+    """$JAX_COMPILATION_CACHE_DIR (read by JAX itself) wins over the
+    entry point's default directory."""
+    env_dir = str(tmp_path / "from-env")
+    monkeypatch.setenv(COMPILE_CACHE_ENV, env_dir)
+    jax.config.update("jax_compilation_cache_dir", env_dir)
+    assert enable_compile_cache(tmp_path / "default") == env_dir
+    assert jax.config.jax_compilation_cache_dir == env_dir
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
 
 
 def test_stats_ledger_and_reset():
@@ -274,9 +304,12 @@ def test_stats_ledger_and_reset():
     eng.solve_batch([GOOD_SPEC] * 2, frontend=True)
     st = eng.stats
     assert st.batches == 1 and st.lanes == 2 and st.ipm_iterations > 0
+    assert st.cache_misses >= 1 and st.compile_ms > 0
+    eng.solve_batch([GOOD_SPEC] * 2, frontend=True)     # cache hits only
+    assert eng.stats.compile_ms == st.compile_ms
     eng.reset_stats()
     st2 = eng.stats
-    assert st2.lanes == 0 and st2.cache_misses == 0
+    assert st2.lanes == 0 and st2.cache_misses == 0 and st2.compile_ms == 0
     assert eng.compile_cache_info()["size"] >= 1    # cache itself survives
 
 

@@ -27,7 +27,7 @@ try:
 except ImportError:  # offline fallback: seeded-random shim
     from _hyp import given, settings, strategies as st
 
-from repro.core.dlt import DLTEngine, SystemSpec, solve
+from repro.core.dlt import DLTEngine, InfeasibleError, SystemSpec, solve
 from repro.core.dlt.stacking import BatchedSystemSpec
 
 REL_TOL = 1e-6
@@ -65,10 +65,17 @@ def _mi_spec(seed, m, r):
 @settings(max_examples=10, deadline=None)
 @given(n=st.integers(1, 5), m=st.integers(1, 8), seed=st.integers(0, 10**6))
 def test_resource_sharing_uncontended_is_frontend(n, m, seed):
-    """ell = 0: EqL degenerates to T_f >= R_1 (implied by Eq 5)."""
+    """ell = 0: EqL degenerates to T_f >= R_1 (implied by Eq 5) — the
+    same program, so the same optimum or, when the releases cannot be
+    chained (Eq 3), the same infeasibility verdict."""
     spec = _rs_spec(seed, n, m, ell=0.0)
+    try:
+        ref = solve(spec, frontend=True).finish_time
+    except InfeasibleError:
+        with pytest.raises(InfeasibleError):
+            solve(spec, formulation="resource_sharing")
+        return
     got = solve(spec, formulation="resource_sharing").finish_time
-    ref = solve(spec, frontend=True).finish_time
     assert got == pytest.approx(ref, rel=REL_TOL)
 
 

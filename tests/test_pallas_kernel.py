@@ -5,9 +5,10 @@ The Pallas port must reproduce the pure-JAX scan reference
 1e-6 certification tolerance.  CI runs these in interpret mode (the
 kernel body executes as plain jnp ops), which is exactly what
 ``EngineConfig.pallas_interpret`` enables on CPU; routing tests cover
-the ``kernel="pallas_banded"`` tier — pinned on an unsupported backend
-raises, ``auto`` falls back to the banded scans with the fallback
-recorded in ``stats.kernel_fallbacks``.
+the ``kernel="pallas_banded"`` tier — ``auto`` takes it on the TPU for
+the mixed policy's fp32 factor only, and pinning it where it cannot
+run (off the TPU without interpret, under fp64, interpret on the TPU)
+raises.
 """
 
 import numpy as np
@@ -17,6 +18,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.dlt import DLTEngine, EngineConfig, SystemSpec
+from repro.core.dlt.batched import build_family_lp
+from repro.core.dlt.stacking import BatchedSystemSpec
+from repro.core.dlt.precision import x64_scope
 from repro.kernels.dlt_banded_chol import ops, ref
 
 REL_TOL = 1e-6
@@ -46,7 +50,7 @@ def _random_arrowhead(rng, K, s, p):
 def test_pallas_factor_solve_parity(K, s, p):
     """Interpret-mode Pallas == scan ref == direct dense solve."""
     rng = np.random.default_rng(K * 100 + s * 10 + p)
-    with jax.experimental.enable_x64():
+    with x64_scope():
         M, Dblk, Opad, Ublk, Db, rband, rb, rhs = _random_arrowhead(
             rng, K, s, p)
         j = lambda a: jnp.asarray(a, jnp.float64)
@@ -71,7 +75,7 @@ def test_pallas_factor_solve_parity(K, s, p):
 def test_pallas_parity_under_vmap():
     """vmap prepends the batch grid axis; scratch carries stay per-lane."""
     rng = np.random.default_rng(0)
-    with jax.experimental.enable_x64():
+    with x64_scope():
         lanes = [_random_arrowhead(rng, 4, 3, 1) for _ in range(5)]
         Dv = jnp.asarray(np.stack([l[1] for l in lanes]), jnp.float64)
         Ov = jnp.asarray(np.stack([l[2] for l in lanes]), jnp.float64)
@@ -96,6 +100,8 @@ def test_pallas_supported_matrix():
     assert not ops.pallas_supported(backend="cpu")
     assert not ops.pallas_supported(backend="gpu")
     assert ops.pallas_supported(backend="cpu", interpret=True)
+    # interpret mode is the CPU parity tool; it never runs on the chip
+    assert not ops.pallas_supported(backend="tpu", interpret=True)
 
 
 # ---------------------------------------------------------------------------
@@ -116,7 +122,7 @@ def _specs(seed, count, n, m):
 def test_engine_pallas_tier_matches_structured():
     specs = _specs(1, 4, 2, 6)
     pal = DLTEngine(kernel="pallas_banded", pallas_interpret=True,
-                    verify=False, oracle_fallback=False)
+                    precision="mixed", verify=False, oracle_fallback=False)
     st = DLTEngine(kernel="structured", verify=False, oracle_fallback=False)
     a = pal.solve_batch(specs, frontend=False)
     b = st.solve_batch(specs, frontend=False)
@@ -129,24 +135,30 @@ def test_engine_pallas_tier_matches_structured():
     assert pal.stats.banded_lanes == 0
 
 
+def _auto_plan(**config):
+    """The kernel plan ``auto`` resolves for a banded-capable family."""
+    eng = DLTEngine(**config)
+    bs = BatchedSystemSpec.from_specs(_specs(2, 3, 2, 16))  # ~50 rows
+    fm = eng._formulation(False, None)
+    return eng._kernel_plan(fm, bs, build_family_lp(bs, fm))
+
+
 def test_auto_upgrades_to_pallas_on_supported_backend(monkeypatch):
-    """On a backend with the lowering (TPU; interpret stands in for it
-    here) auto upgrades banded-capable families to the Pallas tier,
-    recorded in stats.pallas_lanes."""
+    """On the TPU, auto gives the Pallas tier the one program the chip's
+    kernel compiler accepts: the mixed policy's fp32 factor.  fp64
+    factors stay on the XLA scans, and interpret mode is never routed to
+    (tests/test_tpu_compile.py compiles the routed program for a v5e)."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    eng = DLTEngine(pallas_interpret=True, verify=False,
-                    oracle_fallback=False)
-    specs = _specs(2, 3, 2, 16)               # ~50 rows >= break-even
-    eng.solve_batch(specs, frontend=False)
-    assert eng.stats.pallas_lanes == len(specs)
-    assert eng.stats.banded_lanes == 0
-    assert eng.stats.kernel_fallbacks == 0
+    assert _auto_plan(precision="mixed").kind == "pallas_banded"
+    assert _auto_plan(precision="fp64").kind == "banded"
+    assert _auto_plan(precision="mixed",
+                      pallas_interpret=True).kind == "banded"
 
 
 def test_interpret_opt_in_never_changes_auto_routing():
     """pallas_interpret is a parity knob for PINNED pallas_banded — on
     CPU, auto keeps the fast scan kernels even with it set."""
-    eng = DLTEngine(pallas_interpret=True, verify=False,
+    eng = DLTEngine(pallas_interpret=True, precision="mixed", verify=False,
                     oracle_fallback=False)
     specs = _specs(2, 3, 2, 16)
     eng.solve_batch(specs, frontend=False)
@@ -155,24 +167,34 @@ def test_interpret_opt_in_never_changes_auto_routing():
     assert eng.stats.kernel_fallbacks == 0
 
 
-def test_pinned_pallas_raises_on_unsupported_backend():
-    eng = DLTEngine(kernel="pallas_banded")   # no interpret opt-in, CPU
+def test_pinned_pallas_raises_on_unsupported_backend(monkeypatch):
+    specs = _specs(3, 2, 2, 6)
+    eng = DLTEngine(kernel="pallas_banded", precision="mixed")  # CPU
     with pytest.raises(ValueError, match="not supported"):
-        eng.solve_batch(_specs(3, 2, 2, 6), frontend=False)
+        eng.solve_batch(specs, frontend=False)
+    # the kernel factors fp32 blocks only: the fp64 policy cannot pin it
+    with pytest.raises(ValueError, match="precision='mixed'"):
+        eng.configured(precision="fp64").solve_batch(specs, frontend=False)
+    # interpret mode never stands in for the compiled kernel on the chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="'tpu'"):
+        eng.configured(pallas_interpret=True).solve_batch(
+            specs, frontend=False)
 
 
 def test_auto_falls_back_and_records_on_candidate_backend(monkeypatch):
-    """A backend that makes Pallas a candidate but has no lowering (the
-    GPU case) falls back to the banded scans, visibly."""
+    """A backend without the Pallas lowering (GPU here) runs auto's
+    banded families on the XLA scans: the Pallas tier is a TPU-only
+    route, not a downgrade, so no kernel fallback is recorded."""
     monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
     eng = DLTEngine(verify=False, oracle_fallback=False,
-                    banded_min_rows=32)
+                    banded_min_rows=32, precision="mixed")
     specs = _specs(4, 3, 2, 16)
     sol = eng.solve_batch(specs, frontend=False)
-    assert eng.stats.kernel_fallbacks >= 1
+    assert eng.stats.kernel_fallbacks == 0
     assert eng.stats.banded_lanes == len(specs)
     assert eng.stats.pallas_lanes == 0
-    ref_sol = DLTEngine(kernel="banded", verify=False,
+    ref_sol = DLTEngine(kernel="banded", precision="mixed", verify=False,
                         oracle_fallback=False).solve_batch(
                             specs, frontend=False)
     ok = (sol.status == 0) & (ref_sol.status == 0)

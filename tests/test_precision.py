@@ -16,6 +16,7 @@ Three contracts from the precision policy (README "Precision policy"):
 """
 
 import numpy as np
+import pytest
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -89,9 +90,11 @@ def test_mixed_matches_fp64(seed, kind):
     # telemetry shape contract: mixed carries per-lane counters, fp64
     # carries none
     assert s64.refine_iterations is None
+    assert s64.phase1_handover is None
     assert s64.precision_fallback_mask is None
     assert smx.refine_iterations is not None
     assert smx.refine_iterations.shape == (len(specs),)
+    assert smx.phase1_handover.shape == (len(specs),)
     assert smx.precision_fallback_mask is not None
     _assert_parity(s64, smx)
 
@@ -128,6 +131,27 @@ def test_stalled_phase1_falls_back_to_fp64(monkeypatch):
     sol = eng.solve_batch(specs, frontend=False)
     assert eng.stats.precision_fallback_lanes > 0
     assert bool(np.asarray(sol.precision_fallback_mask).any())
+    _assert_parity(ENG64.solve_batch(specs, frontend=False), sol)
+
+
+@pytest.mark.parametrize("kernel", ["structured", "banded"])
+def test_failed_fp32_factor_hands_over_to_fp64(monkeypatch, kernel):
+    """A negative ridge makes every fp32 factor non-SPD (NaN directions,
+    so phase 1 cannot take a step).  The lane must leave phase 1 at
+    once, say so (``HANDOVER_NO_STEP``, counted per lane) and certify in
+    the fp64 loop of the same executable, not burn its budget there and
+    need the fp64 re-solve."""
+    monkeypatch.setattr(_precision, "FP32_RIDGE", -4.0)
+    # off-default refine_tol: the patched ridge is baked in at trace
+    # time but is not part of the compile-cache key
+    eng = DLTEngine(precision="mixed", kernel=kernel, refine_tol=3.3e-7,
+                    **_BASE)
+    specs = _family(np.random.default_rng(3), 6)
+    sol = eng.solve_batch(specs, frontend=False)
+    assert np.all(sol.status == STATUS_OPTIMAL)
+    assert eng.stats.precision_fallback_lanes == 0
+    assert np.all(sol.phase1_handover == _precision.HANDOVER_NO_STEP)
+    assert eng.stats.phase1_handover_lanes == len(specs)
     _assert_parity(ENG64.solve_batch(specs, frontend=False), sol)
 
 

@@ -382,6 +382,44 @@ def test_stop_flushes_pending():
     assert f.result(timeout=0).shares.sum() == 21
 
 
+class _FailingSolver:
+    """Engine view whose window solves raise (a compile error stand-in)."""
+
+    def __init__(self, engine):
+        self._engine = engine
+
+    def solve_batch_carry(self, *args, **kwargs):
+        raise RuntimeError("injected window solve failure")
+
+    def __getattr__(self, name):
+        return getattr(self._engine, name)
+
+
+def test_failed_window_solve_fails_futures_fast_and_loop_survives(caplog):
+    svc = RouterService(fleet(), ServiceConfig(admit_window_ms=5.0))
+    good_solver = svc._solver
+    svc._solver = _FailingSolver(good_solver)
+    with svc:
+        futs = [svc.submit(j) for j in (5, 9)]
+        for f in futs:
+            with pytest.raises(RuntimeError, match="injected window solve"):
+                f.result(timeout=10)
+        assert svc.stats.failed_decisions == 2
+        assert svc.stats.decisions == 0
+        # the loop thread is still alive and serves the next window
+        svc._solver = good_solver
+        assert svc.submit(13).result(timeout=60).shares.sum() == 13
+    assert svc.stats.failed_decisions == 2
+    assert svc.stats.decisions == 1
+    # a drift probe window (empty queue) has no future to fail: its
+    # error is logged, not lost
+    svc._solver = _FailingSolver(good_solver)
+    svc.observe(np.asarray(FLEET_A) * 3.0)
+    assert svc.step() == 0
+    assert "drift probe window failed" in caplog.text
+    assert svc.stats.failed_decisions == 2
+
+
 # ---------------------------------------------------------------------------
 # config validation
 # ---------------------------------------------------------------------------

@@ -214,6 +214,12 @@ def _hsde_ipm_core(c, b, A_mul, AT_mul, make_normal_solver,
     ``mu > SWITCH_MU * mu0`` (where cond(M) is benign and the arithmetic
     win lives) and each step lowers mu, then the plain fp64 loop to
     certification, so the stopping test is bitwise the fp64 policy's.
+
+    Named scopes give the device trace stable names for the parts of an
+    iteration: ``ipm.step`` holds the whole body, ``ipm.normal`` the
+    build and factor of the normal equations (``ipm.factor`` the factor
+    alone, set by each instantiation) and ``ipm.solve`` each solve with
+    the factor.  They change metadata only, never the computation.
     """
     n = c.shape[0]
     m = b.shape[0]
@@ -269,7 +275,12 @@ def _hsde_ipm_core(c, b, A_mul, AT_mul, make_normal_solver,
             # the instantiation (dense/structured: Cholesky of the full
             # matrix; banded: block-tridiagonal-arrowhead Cholesky)
             dinv = x / s
-            solve_M = solver_of_dinv(dinv)
+            with jax.named_scope("ipm.normal"):
+                solve_normal = solver_of_dinv(dinv)
+
+            def solve_M(rhs):
+                with jax.named_scope("ipm.solve"):
+                    return solve_normal(rhs)
 
             def A_d_mul(r):  # A diag(dinv) r
                 return A_mul(dinv * r)
@@ -328,7 +339,11 @@ def _hsde_ipm_core(c, b, A_mul, AT_mul, make_normal_solver,
             return (x, y, s, tau, kappa, status, done | done_now,
                     nit + 1, nref + nr_v + nr_a + nr_c)
 
-        return body
+        def scoped_body(carry):
+            with jax.named_scope("ipm.step"):
+                return body(carry)
+
+        return scoped_body
 
     status0, done0 = classify(x0, y0, s0, tau0, kappa0)
     carry0 = (x0, y0, s0, tau0, kappa0, status0, done0, jnp.asarray(0),
@@ -391,7 +406,8 @@ def _chol_solver(Mmat):
     """Factor a dense normal matrix (+ tiny relative ridge) -> solver."""
     m = Mmat.shape[0]
     Mmat = Mmat + (1e-13 * (jnp.trace(Mmat) / m + 1.0)) * jnp.eye(m)
-    L = jnp.linalg.cholesky(Mmat)
+    with jax.named_scope("ipm.factor"):
+        L = jnp.linalg.cholesky(Mmat)
 
     def solve_M(rhs):  # rhs (m,) or (m, k)
         z = jax.scipy.linalg.solve_triangular(L, rhs, lower=True)
@@ -847,7 +863,8 @@ def _banded_ops(geom: BandedGeometry, F, ext, dcoef, colix,
         Opad = jnp.concatenate(
             [jnp.zeros((1, s, s), dtype=rhs_dtype), Oblk[:-1]], axis=0)
 
-        C, X, V, Cb = _chol_kernels.factor(Dblk, Opad, Ublk, Db)
+        with jax.named_scope("ipm.factor"):
+            C, X, V, Cb = _chol_kernels.factor(Dblk, Opad, Ublk, Db)
         return lambda rhs: _band_solve(C, X, V, Cb, rhs)
 
     def _band_mul(D64, O64, U64, Db64):
@@ -910,8 +927,10 @@ def _banded_ops(geom: BandedGeometry, F, ext, dcoef, colix,
 
                 Opad = jnp.concatenate(
                     [jnp.zeros((1, s, s), dtype=f32), Oblk[:-1]], axis=0)
-                C, X, V, Cb = _chol_kernels.factor(
-                    Dblk, Opad, Ublk, Db, impl=impl, interpret=interpret)
+                with jax.named_scope("ipm.factor"):
+                    C, X, V, Cb = _chol_kernels.factor(
+                        Dblk, Opad, Ublk, Db, impl=impl,
+                        interpret=interpret)
 
                 # position-space row scale S: solve M w = r via the
                 # factored S M S with w = S solve(S r)

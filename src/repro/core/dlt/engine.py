@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ... import tracing
 from ...kernels.dlt_banded_chol import ops as _chol_kernels
 from . import precision as _precision
 from .batched import (
@@ -80,7 +81,12 @@ from .batched import (
     densify_family,
 )
 from .cost import ProcessorSweep
-from .executors import Executor, available_executors, resolve_executor
+from .executors import (
+    Executor,
+    available_executors,
+    microbatch_slots,
+    resolve_executor,
+)
 from .formulations import (
     BatchFields,
     Formulation,
@@ -394,7 +400,6 @@ class EngineStats:
 
     batches: int = 0            # solve_batch calls completed
     lanes: int = 0              # scenarios solved through the IPM
-    cold_lanes: int = 0         # lanes started from the cold HSDE point
     warm_lanes: int = 0         # lanes restarted from an anchor solution
     cold_iterations: int = 0    # IPM iterations spent on cold lanes
     warm_iterations: int = 0    # IPM iterations spent on warm lanes
@@ -420,6 +425,12 @@ class EngineStats:
                                 # ended on a step that did not lower mu
     transfer_lanes: int = 0     # anchors warm-seeded from a neighboring
                                 # bucket via cross-bucket dual transfer
+    ipm_lane_slots: int = 0     # lane-iterations the executables issued:
+                                # per micro-batch, its width (pad lanes
+                                # included) x its slowest lane's iterations
+    lp_cells: int = 0           # sum of N*M over the lanes solved through
+                                # the IPM
+    lp_cell_slots: int = 0      # sum of N_pad*M_pad over the same lanes
 
     @property
     def ipm_iterations(self) -> int:
@@ -474,14 +485,15 @@ class _EngineState:
         self.counter_lock = threading.Lock()
         self.scopes = threading.local()
         self.counters = dict(
-            batches=0, lanes=0, cold_lanes=0, warm_lanes=0,
+            batches=0, lanes=0, warm_lanes=0,
             cold_iterations=0, warm_iterations=0, banded_lanes=0,
             pallas_lanes=0, kernel_fallbacks=0,
             resolve_lanes=0, fallback_lanes=0,
             cache_hits=0, cache_misses=0,
             cache_lookups=0, cache_contention=0, compile_ms=0,
             refine_iterations=0, precision_fallback_lanes=0,
-            phase1_handover_lanes=0, transfer_lanes=0)
+            phase1_handover_lanes=0, transfer_lanes=0,
+            ipm_lane_slots=0, lp_cells=0, lp_cell_slots=0)
 
     def bump(self, **by):
         with self.counter_lock:
@@ -534,6 +546,16 @@ def enable_compile_cache(default_dir: Union[str, os.PathLike]) -> str:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return jax.config.jax_compilation_cache_dir
+
+
+def _executable_name(kind: str, precision: str, warm: bool) -> str:
+    """The compiled IPM's module name, e.g. ``dlt_ipm.banded.fp64.cold``.
+
+    It names the executable's events in a profiler trace (as
+    ``jit_<name>``), so that a reading of them survives edits of the
+    program.
+    """
+    return f"dlt_ipm.{kind}.{precision}.{'warm' if warm else 'cold'}"
 
 
 def _family_take(fam: FamilyLP, pos: np.ndarray) -> FamilyLP:
@@ -910,11 +932,16 @@ class DLTEngine:
             st.bump(cache_hits=1, cache_contention=1)
             return latch.exe
         st.bump(cache_misses=1)
+        prec = self._precision_policy()
         t0 = time.perf_counter()
         try:
             fn, in_axes, args = self._kernel_signature(plan, B, warm,
                                                        max_iter)
-            exe = executor.compile(fn, in_axes, args)
+            with tracing.span("dlt.compile", kernel=plan.kind,
+                              precision=prec, B=B, warm=warm):
+                exe = executor.compile(
+                    fn, in_axes, args,
+                    name=_executable_name(plan.kind, prec, warm))
         except BaseException as e:
             latch.exc = e
             raise
@@ -1085,45 +1112,57 @@ class DLTEngine:
                 Bp = executor.pad_batch(Bk, warm)
                 chunk = np.arange(lo, hi)
                 bchunk = None
-                if plan.kind in ("banded", "pallas_banded"):
-                    bchunk = _banded_take(plan.bfam, chunk)
-                    parts = [bchunk.c, bchunk.F, bchunk.b, bchunk.ext,
-                             bchunk.dcoef, bchunk.Fg, bchunk.Hg, bchunk.Ug,
-                             bchunk.Bq]
-                    if warm:
-                        parts += list(banded_warm_convert(
-                            bchunk, *(a[lo:hi] for a in init)))
-                elif plan.kind == "dense":
-                    parts = [fam.c[lo:hi], plan.A[lo:hi], fam.b[lo:hi]]
-                    if warm:
-                        parts += [a[lo:hi] for a in init]
-                else:
-                    parts = [fam.c[lo:hi], fam.F[lo:hi], fam.b[lo:hi],
-                             fam.art[lo:hi]]
-                    if warm:
-                        parts += [a[lo:hi] for a in init]
-                if Bp != Bk:
-                    parts = [np.concatenate(
-                        [p, np.repeat(p[-1:], Bp - Bk, axis=0)])
-                        for p in parts]
+                with tracing.span("dlt.assemble"):
+                    if plan.kind in ("banded", "pallas_banded"):
+                        bchunk = _banded_take(plan.bfam, chunk)
+                        parts = [bchunk.c, bchunk.F, bchunk.b, bchunk.ext,
+                                 bchunk.dcoef, bchunk.Fg, bchunk.Hg,
+                                 bchunk.Ug, bchunk.Bq]
+                        if warm:
+                            parts += list(banded_warm_convert(
+                                bchunk, *(a[lo:hi] for a in init)))
+                    elif plan.kind == "dense":
+                        parts = [fam.c[lo:hi], plan.A[lo:hi], fam.b[lo:hi]]
+                        if warm:
+                            parts += [a[lo:hi] for a in init]
+                    else:
+                        parts = [fam.c[lo:hi], fam.F[lo:hi], fam.b[lo:hi],
+                                 fam.art[lo:hi]]
+                        if warm:
+                            parts += [a[lo:hi] for a in init]
+                    if Bp != Bk:
+                        parts = [np.concatenate(
+                            [p, np.repeat(p[-1:], Bp - Bk, axis=0)])
+                            for p in parts]
                 exe = self._executable(plan, Bp, warm, mi)
-                jparts = [jnp.asarray(p, jnp.float64) for p in parts]
-                if plan.kind in ("banded", "pallas_banded"):
-                    jparts.insert(5, jnp.asarray(plan.bfam.colix))
-                x, _, st, ni, y, s, nref, ho = exe(*jparts)
-                xs.append(np.asarray(x)[:Bk])
-                sts.append(np.asarray(st)[:Bk])
-                nits.append(np.asarray(ni)[:Bk])
-                nrefs.append(np.asarray(nref)[:Bk])
-                hos.append(np.asarray(ho)[:Bk])
+                with tracing.span("dlt.to_device"):
+                    jparts = [jnp.asarray(p, jnp.float64) for p in parts]
+                    if plan.kind in ("banded", "pallas_banded"):
+                        jparts.insert(5, jnp.asarray(plan.bfam.colix))
+                    if tracing.active():
+                        jax.block_until_ready(jparts)
+                # the copies below wait for the outputs anyway: blocking
+                # here first moves no wait, it only ends the span there
+                with tracing.span("dlt.ipm"):
+                    outs = jax.block_until_ready(exe(*jparts))
+                with tracing.span("dlt.from_device"):
+                    x, _, st, ni, y, s, nref, ho = outs
+                    ni = np.asarray(ni)
+                    xs.append(np.asarray(x)[:Bk])
+                    sts.append(np.asarray(st)[:Bk])
+                    nits.append(ni[:Bk])
+                    nrefs.append(np.asarray(nref)[:Bk])
+                    hos.append(np.asarray(ho)[:Bk])
+                    if want_state:
+                        yk = np.asarray(y)[:Bk]
+                        ss.append(np.asarray(s)[:Bk])
                 self._state.bump(
-                    phase1_handover_lanes=np.count_nonzero(hos[-1]))
+                    phase1_handover_lanes=np.count_nonzero(hos[-1]),
+                    ipm_lane_slots=microbatch_slots(ni))
                 if want_state:
-                    yk = np.asarray(y)[:Bk]
                     if plan.kind in ("banded", "pallas_banded"):
                         yk = banded_dual_to_std(bchunk, yk)
                     ys.append(yk)
-                    ss.append(np.asarray(s)[:Bk])
         out = (np.concatenate(xs), np.concatenate(sts), np.concatenate(nits),
                np.concatenate(nrefs), np.concatenate(hos))
         if want_state:
@@ -1367,7 +1406,10 @@ class DLTEngine:
         st8 = self._state
         cfg = self.config
         B = fam.c.shape[0]
-        plan = self._kernel_plan(fm, sub, fam)
+        with tracing.span("dlt.assemble"):
+            plan = self._kernel_plan(fm, sub, fam)
+        cells = sub.cell_mask
+        st8.bump(lp_cells=np.count_nonzero(cells), lp_cell_slots=cells.size)
         if plan.kind == "banded":
             st8.bump(banded_lanes=B)
         elif plan.kind == "pallas_banded":
@@ -1403,7 +1445,7 @@ class DLTEngine:
                              cold_iterations=fout[2].sum())
                 st8.bump(lanes=B)
             else:
-                st8.bump(lanes=B, cold_lanes=B, cold_iterations=ni.sum())
+                st8.bump(lanes=B, cold_iterations=ni.sum())
             carry = None
             if want_carry:
                 carry = self._make_carry(fm, sub, fam, plan, np.arange(B),
@@ -1436,7 +1478,7 @@ class DLTEngine:
                 st8.bump(resolve_lanes=failed.size,
                          cold_iterations=nif.sum())
         else:
-            st8.bump(cold_lanes=anchor.size, cold_iterations=nia.sum())
+            st8.bump(cold_iterations=nia.sum())
         carry = None
         if want_carry:
             carry = self._make_carry(fm, sub, fam, plan, anchor,
@@ -1609,15 +1651,30 @@ class DLTEngine:
             presorted: bool = False, warm: bool = False,
             carry_in: Optional[dict] = None, want_carry: bool = False,
     ) -> Tuple[BatchedSolution, dict]:
+        with tracing.span("dlt.solve_batch") as root:
+            cfg = self.config
+            fm = self._formulation(frontend, formulation)
+            with tracing.span("dlt.assemble"):
+                bspec = (specs if isinstance(specs, BatchedSystemSpec)
+                         else BatchedSystemSpec.from_specs(
+                             specs, presorted=presorted))
+            if cfg.engine == "scalar":
+                # honor the config contract: the scalar engine keeps the
+                # one-LP-at-a-time loop (and its pinned solver) on every
+                # path
+                root.set(lanes=bspec.batch)
+                return (self._solve_batch_scalar(bspec, frontend,
+                                                 formulation), {})
+            return self._solve_batch_groups(
+                root, bspec, fm, warm=warm, carry_in=carry_in,
+                want_carry=want_carry)
+
+    def _solve_batch_groups(self, root, bspec: BatchedSystemSpec,
+                            fm: Formulation, *, warm: bool,
+                            carry_in: Optional[dict], want_carry: bool,
+                            ) -> Tuple[BatchedSolution, dict]:
+        """The batched engine's :meth:`solve_batch` under its root span."""
         cfg = self.config
-        fm = self._formulation(frontend, formulation)
-        bspec = (specs if isinstance(specs, BatchedSystemSpec)
-                 else BatchedSystemSpec.from_specs(specs, presorted=presorted))
-        if cfg.engine == "scalar":
-            # honor the config contract: the scalar engine keeps the
-            # one-LP-at-a-time loop (and its pinned solver) on every path
-            return (self._solve_batch_scalar(bspec, frontend, formulation),
-                    {})
         frontend = fm.frontend
         B, Nmax, Mmax = bspec.batch, bspec.n_max, bspec.m_max
 
@@ -1635,7 +1692,10 @@ class DLTEngine:
         pfb_all = np.zeros(B, dtype=bool)
 
         m_edges = WARM_M_BUCKET_EDGES if warm else cfg.m_bucket_edges
-        groups = list(_group_lanes(bspec, cfg.bucket, m_edges, fm=fm).items())
+        with tracing.span("dlt.assemble"):
+            groups = list(_group_lanes(bspec, cfg.bucket, m_edges,
+                                       fm=fm).items())
+        root.set(lanes=B, groups=len(groups))
         if warm:
             # visit buckets of one source count in ascending M-edge order
             # so each bucket's anchors can seed the next (cross-bucket
@@ -1653,8 +1713,9 @@ class DLTEngine:
             mb = min(mb, int(bspec.n_procs[idx].max()))
             if warm:  # anchors seed neighbors: order the family by size
                 idx = idx[np.argsort(bspec.n_procs[idx], kind="stable")]
-            sub = bspec.take(idx, n_pad=nb, m_pad=mb)
-            fam = build_family_lp(sub, fm)
+            with tracing.span("dlt.assemble"):
+                sub = bspec.take(idx, n_pad=nb, m_pad=mb)
+                fam = build_family_lp(sub, fm)
             transfer = (carry_by_nb.get(ckey)
                         if warm and cfg.warm_transfer else None)
             x, st, ni, nref, ho, pfb, carry = self._solve_group(
@@ -1665,20 +1726,23 @@ class DLTEngine:
             # clean first (exact zeros on padded cells — the IPM leaves
             # ~tol-level dust on masked vars), verify per group so
             # formulation extras (per-round splits etc.) reach the checks
-            fields = fm.clean_batch(sub, fm.unpack_batch(sub, x))
+            with tracing.span("dlt.unpack"):
+                fields = fm.clean_batch(sub, fm.unpack_batch(sub, x))
             if cfg.verify:
-                verified[idx] = fm.verify_batch(sub, fields)
-            sl = np.ix_(idx, np.arange(nb), np.arange(mb))
-            beta[sl] = fields.beta
-            finish[idx] = fields.finish
-            if fm.has_intervals:
-                TS[sl] = fields.TS
-                TF[sl] = fields.TF
-            status[idx] = st
-            iters[idx] = ni
-            refits[idx] = nref
-            handover[idx] = ho
-            pfb_all[idx] = pfb
+                with tracing.span("dlt.verify"):
+                    verified[idx] = fm.verify_batch(sub, fields)
+            with tracing.span("dlt.unpack"):
+                sl = np.ix_(idx, np.arange(nb), np.arange(mb))
+                beta[sl] = fields.beta
+                finish[idx] = fields.finish
+                if fm.has_intervals:
+                    TS[sl] = fields.TS
+                    TF[sl] = fields.TF
+                status[idx] = st
+                iters[idx] = ni
+                refits[idx] = nref
+                handover[idx] = ho
+                pfb_all[idx] = pfb
 
         # exact zeros on padding of lanes no group wrote (defensive)
         cell = bspec.cell_mask
@@ -1694,7 +1758,7 @@ class DLTEngine:
             ok &= verified
 
         fallback_mask = ~ok
-        if cfg.oracle_fallback:
+        if cfg.oracle_fallback and fallback_mask.any():
             # every uncertified lane — including IPM infeasibility verdicts,
             # which the simplex either confirms or overturns with a
             # solution.  Classic-oracle formulations re-check against the
@@ -1702,30 +1766,33 @@ class DLTEngine:
             # their own scalar LP (there is no independent paper program).
             fkw = ({} if self._caps(fm).oracle_kind == "classic"
                    else {"formulation": fm})
-            for k in np.flatnonzero(~ok):
-                try:
-                    sched = _scalar_solve(
-                        bspec.scenario(k), frontend=frontend,
-                        solver="simplex", presorted=True, **fkw)
-                except InfeasibleError:
-                    status[k] = STATUS_INFEASIBLE
-                    continue
-                sp = sched.spec
-                n, m = sp.num_sources, sp.num_processors
-                beta[k] = 0.0
-                beta[k, :n, :m] = fm.fold_schedule(sched)
-                finish[k] = sched.finish_time
-                if TS is not None:
-                    TS[k] = 0.0
-                    TF[k] = 0.0
-                    if sched.TS is not None:
-                        TS[k, :n, :m] = sched.TS
-                        TF[k, :n, :m] = sched.TF
-                    else:
-                        # Sec 2 closed form (single source): back-to-back
-                        TS[k, 0, :m], TF[k, 0, :m] = single_source_intervals(
-                            sp.R[0], sp.G[0], sched.beta[0])
-                status[k] = STATUS_OPTIMAL
+            with tracing.span("dlt.oracle"):
+                for k in np.flatnonzero(~ok):
+                    try:
+                        sched = _scalar_solve(
+                            bspec.scenario(k), frontend=frontend,
+                            solver="simplex", presorted=True, **fkw)
+                    except InfeasibleError:
+                        status[k] = STATUS_INFEASIBLE
+                        continue
+                    sp = sched.spec
+                    n, m = sp.num_sources, sp.num_processors
+                    beta[k] = 0.0
+                    beta[k, :n, :m] = fm.fold_schedule(sched)
+                    finish[k] = sched.finish_time
+                    if TS is not None:
+                        TS[k] = 0.0
+                        TF[k] = 0.0
+                        if sched.TS is not None:
+                            TS[k, :n, :m] = sched.TS
+                            TF[k, :n, :m] = sched.TF
+                        else:
+                            # Sec 2 closed form (single source):
+                            # back-to-back
+                            TS[k, 0, :m], TF[k, 0, :m] = (
+                                single_source_intervals(
+                                    sp.R[0], sp.G[0], sched.beta[0]))
+                    status[k] = STATUS_OPTIMAL
 
         infeasible = status == STATUS_INFEASIBLE
         finish[infeasible] = np.nan

@@ -11,6 +11,7 @@ from .base import (
     LANE_MICROBATCH,
     Executor,
     available_executors,
+    microbatch_slots,
     microbatched,
     resolve_executor,
     _REGISTRY,
@@ -29,6 +30,7 @@ __all__ = [
     "LocalExecutor",
     "ShardedExecutor",
     "available_executors",
+    "microbatch_slots",
     "microbatched",
     "resolve_executor",
 ]

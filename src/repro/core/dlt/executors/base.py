@@ -35,12 +35,15 @@ from __future__ import annotations
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
+import numpy as np
 
 __all__ = [
     "LANE_MICROBATCH",
     "Executor",
     "available_executors",
+    "microbatch_slots",
     "microbatched",
+    "named",
     "resolve_executor",
 ]
 
@@ -89,6 +92,32 @@ def microbatched(fn: Callable, in_axes: Tuple,
         return jax.tree.map(lambda o: o.reshape((B,) + o.shape[2:]), outs)
 
     return run
+
+
+def microbatch_slots(iters: np.ndarray) -> int:
+    """Lane-iterations the :func:`microbatched` program of a chunk issues.
+
+    ``iters`` holds the iteration count of every lane of the padded
+    chunk.  Each micro-batch's ``vmap`` runs its ``while_loop`` until its
+    slowest lane is done, so it issues its width times that lane's
+    count; a chunk below one micro-batch is one group of its own width.
+    (Under ``precision="mixed"`` each of the two loops runs to its own
+    slowest lane, so this is a lower bound there.)
+    """
+    iters = np.asarray(iters)
+    width = min(LANE_MICROBATCH, iters.size)
+    if width == 0:
+        return 0
+    return int(width * iters.reshape(-1, width).max(axis=1).sum())
+
+
+def named(fn: Callable, name: Optional[str]) -> Callable:
+    """``fn`` renamed ``name`` (kept where ``None``): ``jax.jit`` names
+    the compiled module ``jit_<name>``, and a profiler trace names the
+    module's events so."""
+    if name is not None:
+        fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 class Executor:
@@ -157,14 +186,17 @@ class Executor:
         return -(-base // LANE_MICROBATCH) * LANE_MICROBATCH
 
     def compile(self, fn: Callable, in_axes: Tuple[Optional[int], ...],
-                args: Sequence[jax.ShapeDtypeStruct]) -> Callable:
+                args: Sequence[jax.ShapeDtypeStruct],
+                name: Optional[str] = None) -> Callable:
         """AOT-compile the per-lane kernel ``fn`` over stacked arguments.
 
         ``in_axes`` follows :func:`jax.vmap` semantics (0 = stacked
         along the lane axis, ``None`` = shared by every lane) and
         ``args`` are :class:`jax.ShapeDtypeStruct` for the padded
-        stacked shapes.  The returned callable takes the concrete
-        stacked arrays and handles any device placement itself.
+        stacked shapes.  ``name`` names the compiled module (see
+        :func:`named`); it changes no computation.  The returned
+        callable takes the concrete stacked arrays and handles any
+        device placement itself.
         """
         raise NotImplementedError
 

@@ -15,7 +15,7 @@ from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 
-from .base import Executor, LANE_MICROBATCH, microbatched
+from .base import Executor, LANE_MICROBATCH, microbatched, named
 
 __all__ = ["LocalExecutor"]
 
@@ -44,6 +44,7 @@ class LocalExecutor(Executor):
         return microbatched(fn, in_axes)
 
     def compile(self, fn: Callable, in_axes: Tuple[Optional[int], ...],
-                args: Sequence[jax.ShapeDtypeStruct]) -> Callable:
-        return (jax.jit(self.wrap(fn, in_axes, args))
+                args: Sequence[jax.ShapeDtypeStruct],
+                name: Optional[str] = None) -> Callable:
+        return (jax.jit(named(self.wrap(fn, in_axes, args), name))
                 .lower(*args).compile())

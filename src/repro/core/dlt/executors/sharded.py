@@ -40,7 +40,7 @@ from jax import shard_map
 from jax._src.config import use_shardy_partitioner
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .base import Executor, LANE_MICROBATCH, microbatched
+from .base import Executor, LANE_MICROBATCH, microbatched, named
 
 __all__ = ["ShardedExecutor"]
 
@@ -104,11 +104,12 @@ class ShardedExecutor(Executor):
         return self._mapped(fn, in_axes, args[0].shape[0])[0]
 
     def compile(self, fn: Callable, in_axes: Tuple[Optional[int], ...],
-                args: Sequence[jax.ShapeDtypeStruct]) -> Callable:
+                args: Sequence[jax.ShapeDtypeStruct],
+                name: Optional[str] = None) -> Callable:
         mapped, shardings, out_sharding = self._mapped(
             fn, in_axes, args[0].shape[0])
         with use_shardy_partitioner(False):
-            exe = (jax.jit(mapped, in_shardings=shardings,
+            exe = (jax.jit(named(mapped, name), in_shardings=shardings,
                            out_shardings=out_sharding)
                    .lower(*args).compile())
 

@@ -116,7 +116,8 @@ def fp32_cholesky(M64: jnp.ndarray) -> Callable[[jnp.ndarray], jnp.ndarray]:
         Ms = (sc64[:, None] * M64) * sc64[None, :]
         M32 = Ms.astype(jnp.float32)
         M32 = M32 + FP32_RIDGE * jnp.eye(M32.shape[0], dtype=jnp.float32)
-        L32 = jnp.linalg.cholesky(M32)
+        with jax.named_scope("ipm.factor"):
+            L32 = jnp.linalg.cholesky(M32)
 
     def solve32(r: jnp.ndarray) -> jnp.ndarray:
         with jax.named_scope(FP32_FACTOR_SCOPE):

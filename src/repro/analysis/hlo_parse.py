@@ -29,7 +29,7 @@ import re
 from collections import defaultdict
 from typing import Optional
 
-__all__ = ["analyze_hlo", "HloStats"]
+__all__ = ["analyze_hlo", "HloStats", "WhileLoop", "while_loops"]
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -334,3 +334,50 @@ def analyze_hlo(text: str, link_bw: float = 50e9,
         notes=notes,
         unbounded_whiles=unbounded,
     )
+
+
+@dataclasses.dataclass(frozen=True)
+class WhileLoop:
+    """One ``while`` op of a module."""
+    body: str       # its body computation
+    op_name: str    # the op's ``metadata`` op_name ("" where it has none)
+    nested: int     # ``while`` ops reachable from the body, at any depth
+
+
+def while_loops(text: str) -> list:
+    """Every ``while`` op of an HLO module, with the loops inside it.
+
+    A loop counts as inside another where its op sits in the body or in
+    any computation the body calls (fusions, calls, branches, nested
+    loops).  The TPU compiler expands an f64 dot, Cholesky or triangular
+    solve into such nested loops, so ``nested`` says whether a loop body
+    is straight-line code.
+    """
+    comps = _parse(text)
+
+    def callees(cname):
+        subs = set()
+        for ins in comps.get(cname, []):
+            for cm in _CALLS_RE.finditer(ins.attrs):
+                subs.update(s.lstrip("%") for s in re.split(r",\s*", cm.group(1)))
+        return subs & comps.keys()
+
+    memo: dict[str, int] = {}
+
+    def inside(cname):
+        if cname not in memo:
+            memo[cname] = 0                 # a cycle adds nothing
+            memo[cname] = (sum(i.opcode == "while" for i in comps[cname])
+                           + sum(inside(s) for s in callees(cname)))
+        return memo[cname]
+
+    loops = []
+    for instrs in comps.values():
+        for ins in instrs:
+            wm = _WHILE_RE.search(ins.attrs)
+            if ins.opcode == "while" and wm:
+                op = re.search(r'op_name="([^"]*)"', ins.attrs)
+                body = wm.group(2)
+                loops.append(WhileLoop(body, op.group(1) if op else "",
+                                       inside(body) if body in comps else 0))
+    return loops

@@ -54,6 +54,7 @@ import numpy as np
 
 from ... import tracing
 from ...kernels.dlt_banded_chol import ops as _chol_kernels
+from ...kernels.dlt_banded_chol.ref import small_blocks as _small_blocks
 from . import precision as _precision
 from .batched import (
     COMPILE_CACHE_SIZE,
@@ -405,6 +406,9 @@ class EngineStats:
     warm_iterations: int = 0    # IPM iterations spent on warm lanes
     banded_lanes: int = 0       # lanes routed through the banded scan kernel
     pallas_lanes: int = 0       # lanes routed through the Pallas banded kernel
+    small_block_lanes: int = 0  # banded lanes whose fp64 blocks are small
+                                # enough for the elementwise factor and
+                                # substitution steps (see _block_path)
     kernel_fallbacks: int = 0   # auto-routing downgrades (structureless
                                 # formulation -> structured), per lane group
     resolve_lanes: int = 0      # warm lanes re-solved at the full budget
@@ -487,7 +491,7 @@ class _EngineState:
         self.counters = dict(
             batches=0, lanes=0, warm_lanes=0,
             cold_iterations=0, warm_iterations=0, banded_lanes=0,
-            pallas_lanes=0, kernel_fallbacks=0,
+            pallas_lanes=0, small_block_lanes=0, kernel_fallbacks=0,
             resolve_lanes=0, fallback_lanes=0,
             cache_hits=0, cache_misses=0,
             cache_lookups=0, cache_contention=0, compile_ms=0,
@@ -579,6 +583,20 @@ class _KernelPlan:
     fam: FamilyLP
     bfam: Optional[BandedFamilyLP] = None
     A: Optional[np.ndarray] = None
+
+
+def _block_path(plan: _KernelPlan) -> Optional[str]:
+    """How the plan's fp64 banded factor treats its ``s x s`` blocks.
+
+    ``"elementwise"`` where they are small enough for straight-line
+    multiply-and-sum steps (``dlt_banded_chol.ref.small_blocks``),
+    ``"library"`` where they keep the Cholesky / triangular-solve / dot
+    calls, ``None`` for the kernels without a banded factor.
+    """
+    if plan.bfam is None:
+        return None
+    small = _small_blocks(np.float64, plan.bfam.geom.s)
+    return "elementwise" if small else "library"
 
 
 def _plan_take(plan: _KernelPlan, pos: np.ndarray) -> _KernelPlan:
@@ -1143,7 +1161,7 @@ class DLTEngine:
                         jax.block_until_ready(jparts)
                 # the copies below wait for the outputs anyway: blocking
                 # here first moves no wait, it only ends the span there
-                with tracing.span("dlt.ipm"):
+                with tracing.span("dlt.ipm", block_path=_block_path(plan)):
                     outs = jax.block_until_ready(exe(*jparts))
                 with tracing.span("dlt.from_device"):
                     x, _, st, ni, y, s, nref, ho = outs
@@ -1414,6 +1432,8 @@ class DLTEngine:
             st8.bump(banded_lanes=B)
         elif plan.kind == "pallas_banded":
             st8.bump(pallas_lanes=B)
+        if _block_path(plan) == "elementwise":
+            st8.bump(small_block_lanes=B)
         want_carry = (want_carry or warm) and cfg.warm_transfer
 
         if not warm or B <= cfg.warm_stride:

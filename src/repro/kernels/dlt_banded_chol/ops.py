@@ -86,20 +86,25 @@ def banded_solve_bwd(C, Xnext, V, u, wb, *, impl: str = "scan",
 
 def factor(Dblk, Opad, Ublk, Db, *, impl: str = "scan",
            interpret: bool = False):
-    """Full factorization ``(C, X, V, Cb)`` of the arrowhead system."""
+    """Full factorization ``(C, X, V, Cb)`` of the arrowhead system.
+
+    ``C`` (scan) and ``Cb`` hold inverse factors where the blocks are
+    small fp64 (:func:`.ref.small_blocks`); :func:`solve` reads them so.
+    """
     C, X, V, S = banded_factor(Dblk, Opad, Ublk, impl=impl,
                                interpret=interpret)
-    Cb = jnp.linalg.cholesky(Db - S)
-    return C, X, V, Cb
+    return C, X, V, ref.corner_factor(Db, S)
 
 
 def solve(C, X, V, Cb, rband, rb, *, impl: str = "scan",
           interpret: bool = False):
     """Solve from a :func:`factor` result -> ``(wband (K, s), wb (p,))``."""
     u = banded_solve_fwd(C, X, rband, impl=impl, interpret=interpret)
-    t = rb - jnp.einsum("kps,ks->p", V, u)
-    ub = jax.scipy.linalg.solve_triangular(Cb, t, lower=True)
-    wb = jax.scipy.linalg.solve_triangular(Cb.T, ub, lower=False)
+    if ref.small_blocks(V.dtype, V.shape[-1]):
+        t = rb - (V * u[:, None, :]).sum(axis=(0, 2))
+    else:
+        t = rb - jnp.einsum("kps,ks->p", V, u)
+    wb = ref.corner_solve(Cb, t)
     Xnext = jnp.concatenate(
         [X[1:], jnp.zeros((1,) + X.shape[1:], X.dtype)], axis=0)
     wband = banded_solve_bwd(C, Xnext, V, u, wb, impl=impl,

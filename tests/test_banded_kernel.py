@@ -161,6 +161,57 @@ def test_dense_kernel_matches_structured():
                                rtol=REL_TOL, atol=1e-8)
 
 
+def _ragged_family(seed, n_max, m_max, lanes=16):
+    """A ragged family like the planning benchmark's: N cycles over
+    1..n_max and M spreads over 1..m_max, so it pads to n_max x m_max."""
+    rng = np.random.default_rng(seed)
+    ms = np.linspace(1, m_max, lanes).round().astype(int)
+    rng.shuffle(ms)
+    return [SystemSpec(G=rng.uniform(0.5, 0.7, 1 + k % n_max),
+                       R=np.sort(rng.uniform(2.0, 4.0, 1 + k % n_max)),
+                       A=rng.uniform(1.1, 3.0, ms[k]),
+                       J=float(rng.uniform(100.0, 500.0)))
+            for k in range(lanes)]
+
+
+@pytest.mark.parametrize("frontend,n,m,seed", [
+    (False, 3, 20, 0), (False, 3, 20, 1), (True, 4, 16, 2)])
+def test_small_block_path_matches_structured_kernel(frontend, n, m, seed):
+    """The padded families whose fp64 blocks take the elementwise path
+    (3 x 20 without a front end: s = 7; 4 x 16 with one: s = 4) solve as
+    the structured kernel solves them, and the counter sees every lane."""
+    specs = _ragged_family(seed, n, m)
+    eng = DLTEngine(kernel="banded", bucket="none", verify=False,
+                    oracle_fallback=False)
+    st = DLTEngine(kernel="structured", bucket="none", verify=False,
+                   oracle_fallback=False)
+    sol_b = eng.solve_batch(specs, frontend=frontend)
+    sol_s = st.solve_batch(specs, frontend=frontend)
+    assert np.array_equal(sol_b.status, sol_s.status)
+    ok = sol_b.status == 0
+    assert ok.all()
+    np.testing.assert_allclose(sol_b.finish_time, sol_s.finish_time,
+                               rtol=1e-8)
+    assert np.abs(sol_b.iterations - sol_s.iterations).max() <= 1
+    assert eng.stats.banded_lanes == len(specs)
+    assert eng.stats.small_block_lanes == len(specs)
+
+
+def test_wide_blocks_keep_the_library_path():
+    """Sixteen sources without a front end make s = 46 blocks, above
+    SMALL_BLOCK_MAX: the factor keeps the library calls, the counter
+    stays at 0, and the answers still match the structured kernel."""
+    specs = [_random_spec(90 + k, 16, 2) for k in range(2)]
+    eng = DLTEngine(kernel="banded", verify=False, oracle_fallback=False)
+    sol_b = eng.solve_batch(specs, frontend=False)
+    sol_s = ENG_STRUCT.solve_batch(specs, frontend=False)
+    assert np.array_equal(sol_b.status, sol_s.status)
+    np.testing.assert_allclose(sol_b.finish_time, sol_s.finish_time,
+                               rtol=REL_TOL)
+    assert eng.stats.banded_lanes == len(specs)
+    assert eng.stats.small_block_lanes == 0
+
+
 # ---------------------------------------------------------------------------
 # kernel selection: auto routing, fallback, validation
 # ---------------------------------------------------------------------------

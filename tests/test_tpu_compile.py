@@ -10,6 +10,10 @@ here, with no chip:
 * the banded interior-point executable of one routing window (4
   frontends x 128 replicas, one 16-lane micro-batch) under both
   precision policies, with the kernel ``kernel="auto"`` picks on a TPU;
+* the planning benchmark's fp64 banded executable (3 x 20, no front
+  end), whose factor and substitution scans must hold no loop: the
+  chip has no f64 unit, and a library f64 op inside a scan step
+  compiles to loops of its own;
 * each Pallas kernel that ``auto`` can route to, with f32 operands at
   the block shapes of the router and planning families;
 * the sharded executor's program over a four-chip lane mesh.
@@ -29,6 +33,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, SingleDeviceSharding
 
+from repro.analysis.hlo_parse import while_loops
 from repro.core.dlt import DLTEngine, SystemSpec
 from repro.core.dlt.batched import build_family_lp
 from repro.core.dlt.executors import LANE_MICROBATCH
@@ -121,6 +126,29 @@ def test_router_window_ipm_compiles(topo, one_chip, no_persistent_cache,
     assert ("tpu_custom_call" in compiled.as_text()) == (kind != "banded")
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+
+
+def test_planning_ipm_scans_hold_no_loops(topo, one_chip, no_persistent_cache,
+                                          on_tpu):
+    """The fp64 banded factor and substitution scans of the 3 x 20
+    planning program (s = 7 blocks) are straight-line code.
+
+    With the library Cholesky, triangular solves and f64 dots in their
+    steps the program held 313 ``while`` loops (63,490 optimized
+    instructions); with the elementwise small-block steps it holds 148
+    (43,973 instructions), the rest being the f64 dots of the IPM body.
+    """
+    eng = DLTEngine(precision="fp64")
+    plan = _plan(eng, _planning_family(n=3, m=20, lanes=LANE_MICROBATCH))
+    assert plan.kind == "banded" and plan.bfam.geom.s == 7
+    compiled = _compile(LocalExecutor(), plan, eng, LANE_MICROBATCH,
+                        lambda a: one_chip)
+    loops = while_loops(compiled.as_text())
+    scans = [w for w in loops
+             if w.op_name.endswith(("ipm.factor/while", "ipm.solve/while"))]
+    assert len(scans) == 7      # one factor, three forward, three backward
+    assert [w.nested for w in scans] == [0] * 7
+    assert len(loops) < 313 // 2
 
 
 def _geometry(family):

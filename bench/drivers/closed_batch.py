@@ -59,20 +59,26 @@ def run(cell, seed: int, seconds: float, rt):
            compile_s=setup["compile_ms"] / 1e3)
 
     families = traffic.planning_calls(seed, cfg, cell["traffic"])
-    calls = []
+    calls, solve_s = [], []
+    gen_s = 0.0
     t0 = rt.window_start()
     while True:
+        ta = time.perf_counter()
         with rt.span("generator"):
             raw, fresh = next(families)
             specs = _specs(raw)
+        tb = time.perf_counter()
+        gen_s += tb - ta
         with rt.span("planning_call"):
             sol = eng.solve_batch(specs, frontend=False)
         t1 = time.perf_counter()
+        solve_s.append(t1 - tb)
         calls.append((raw, sol, fresh))
         if t1 - t0 >= seconds:
             break
     rt.window_end()
     elapsed = t1 - t0
+    solve_ms = np.array(solve_s) * 1e3
     c = _delta(_counters(eng), setup)
 
     status = np.concatenate([s.status for _, s, _ in calls])
@@ -89,6 +95,8 @@ def run(cell, seed: int, seconds: float, rt):
            oracle_fallback_lanes=c["fallback_lanes"],
            ipm_iterations=c["cold_iterations"] + c["warm_iterations"],
            call_seconds_mean=elapsed / len(calls),
+           solve_ms_quartiles=np.percentile(solve_ms, [25, 50, 75]),
+           solve_ms_max=solve_ms.max(), generator_seconds=gen_s,
            call_iterations_max=[int(s.iterations.max()) for _, s, _ in calls],
            ipm_flops=flops, ipm_bytes=nbytes)
     return SimpleNamespace(

@@ -13,12 +13,12 @@ cell reports and how many chips it needs.
 A run sets up (runtime, compile-cache loads, the cell's warm-up),
 measures for ``--seconds``, then checks its answers against the plain
 reference.  With ``--trace 1`` it measures a window of at most
-``TRACE_SECONDS`` under the profiler and reports the per-layer metrics
-in place of the end-to-end ones, with the device's busy time over the
-part of the window the trace holds (``bench/trace.py``).  The last line
-of standard output is one JSON object; the numbers compared, each
-beside its limit, come last in it and as the last lines of standard
-error.
+``TRACE_SECONDS`` under the profiler, records the program's spans over
+it, and reports the per-layer metrics in place of the end-to-end ones,
+with the device's busy time over the part of the window the trace
+holds (``bench/trace.py``).  The last line of standard output is one
+JSON object; the numbers compared, each beside its limit, come last in
+it and as the last lines of standard error.
 
 Needs an accelerator: where JAX finds none, or fewer chips than the
 cell asks for, it exits non-zero and prints no result.  Run-time files
@@ -57,12 +57,18 @@ class NoChip(RuntimeError):
 
 class Runtime:
     """What a driver needs of the harness: earlier lines, host spans,
-    and the start and end of the measured window."""
+    and the start and end of the measured window.
+
+    A traced window also records the program's spans (``repro.tracing``),
+    kept in :attr:`spans` when it ends.
+    """
 
     def __init__(self, trace_dir=None):
         self.trace_dir = trace_dir
         self.setup_s = None
-        self._window = None
+        self.spans = None
+        self._stack = None
+        self._recorder = None
 
     def say(self, tag: str, **readings) -> None:
         print(f"[{tag}] {json.dumps(readings, default=_plain)}", flush=True)
@@ -75,23 +81,24 @@ class Runtime:
 
     def window_start(self) -> float:
         """Set-up ends here; the traced window, if any, begins."""
+        self._stack = stack = contextlib.ExitStack()
         if self.trace_dir is not None:
             import jax
+            from repro import tracing
             opts = jax.profiler.ProfileOptions()
-            opts.python_tracer_level = 0      # the bench.* spans suffice
+            opts.python_tracer_level = 0      # the host spans suffice
             jax.profiler.start_trace(str(self.trace_dir), profiler_options=opts)
-            self._window = self.span("window")
-            self._window.__enter__()
+            stack.callback(jax.profiler.stop_trace)
+            self._recorder = stack.enter_context(tracing.recording())
+            stack.enter_context(self.span("window"))
         t0 = time.perf_counter()
         self.setup_s = t0 - T_PROCESS
         return t0
 
     def window_end(self) -> None:
-        if self._window is not None:
-            import jax
-            self._window.__exit__(None, None, None)
-            self._window = None
-            jax.profiler.stop_trace()
+        self._stack.close()
+        if self._recorder is not None:
+            self.spans = self._recorder.spans
 
 
 def _plain(v):
@@ -180,10 +187,12 @@ def run(args, require_chip: bool = True, cell: dict = None) -> dict:
     result = {"correct": bool(correct), "attempted": int(window.attempted),
               "failed": int(window.failed)}
     if args.trace:
+        from bench import spans as sp
         from bench import trace as tr
+        rt.say("spans", ms_per_call=sp.per_name(rt.spans))
         reduced = tr.reduce_dir(str(trace_dir))
         shutil.rmtree(trace_dir, ignore_errors=True)
-        ctx = {"layer": window.layer, "trace": reduced,
+        ctx = {"layer": window.layer, "trace": reduced, "spans": rt.spans,
                "device_kind": devs[0].device_kind}
         metrics = {}
         for m in cell["per_layer"]:

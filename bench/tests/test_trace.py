@@ -2,6 +2,7 @@
 
 The host spans come from a real profiler trace; the CPU has no device
 plane, so one with known operations is laid over the recorded window.
+The program's spans reach the trace as ``repro.tracing`` writes them.
 """
 
 import time
@@ -23,13 +24,16 @@ def recorded(tmp_path_factory):
     f = jax.jit(lambda x: jnp.sin(x).sum())
     f(jnp.ones(8)).block_until_ready()
     d = tmp_path_factory.mktemp("trace")
+    from repro import tracing
     jax.profiler.start_trace(str(d))
-    with jax.profiler.TraceAnnotation("bench.window"):
+    with jax.profiler.TraceAnnotation("bench.window"), tracing.recording():
         with jax.profiler.TraceAnnotation("bench.generator"):
             time.sleep(0.02)
         with jax.profiler.TraceAnnotation("bench.planning_call"):
-            f(jnp.ones(8)).block_until_ready()
-            time.sleep(0.06)
+            with tracing.span("dlt.solve_batch"):
+                f(jnp.ones(8)).block_until_ready()
+                with tracing.span("dlt.assemble"):
+                    time.sleep(0.06)
         with jax.profiler.TraceAnnotation("bench.wait_futures"):
             time.sleep(0.02)
     jax.profiler.stop_trace()
@@ -39,7 +43,7 @@ def recorded(tmp_path_factory):
     spans = {ev.name: (ev.start_ns, ev.end_ns)
              for p in planes if p.name.startswith("/host")
              for line in p.lines for ev in line.events
-             if ev.name.startswith("bench.")}
+             if ev.name.startswith(("bench.", "dlt."))}
     return planes, spans
 
 
@@ -70,13 +74,22 @@ def test_reduce_busy_idle_ops_and_gaps(recorded):
     assert [n for n, _ in got["device_ops"]][0] == "fusion.1"
     gaps = got["idle_gaps"]
     assert sum(s for _, s in gaps) == pytest.approx(window - busy)
-    # three gaps, longest first, each named for the host span around it
+    # three gaps, longest first, each named for the innermost host span
+    # around its middle: the harness's or the program's
     want = sorted([("generator", (c0 - w0) * 1e-9),
-                   ("planning_call", third * 1e-9),
+                   ("dlt.assemble", third * 1e-9),
                    ("wait_futures", (w1 - 10 - c1) * 1e-9)],
                   key=lambda g: -g[1])
     assert [n for n, _ in gaps] == [n for n, _ in want]
     assert [s for _, s in gaps] == pytest.approx([s for _, s in want])
+
+
+def test_gap_inside_a_program_span_is_named_for_it(recorded):
+    planes, spans = recorded
+    w0, w1 = spans["bench.window"]
+    a0, a1 = spans["dlt.assemble"]
+    got = trace.reduce(planes + [_device([("op", w0, a0), ("op", a1, w1)])])
+    assert got["idle_gaps"] == [["dlt.assemble", pytest.approx((a1 - a0) * 1e-9)]]
 
 
 def test_window_is_cut_where_trace_buffers_ran_out(recorded):

@@ -16,8 +16,10 @@ profiler writes, this module takes:
 * ``device_ops``: device seconds by operation (its HLO name, the part
   of the event's name before `` = ``), most first;
 * ``idle_gaps``: the longest stretches of the window with no device
-  operation, each named for the innermost ``bench.*`` host span around
-  its middle (``other`` where there is none).
+  operation, each named for the innermost host span around its middle:
+  a ``bench.*`` span of the harness (named without its prefix) or a
+  ``dlt.*`` span of the program (``repro.tracing``, named in full), and
+  ``other`` where there is none.
 
 Host and device events share the profiler's clock.
 """
@@ -29,6 +31,7 @@ import os
 from typing import Optional
 
 SPAN_PREFIX = "bench."
+PROGRAM_PREFIX = "dlt."
 WINDOW = "window"
 OPS_LINE = "XLA Ops"
 DROPPED = "Trace Buffers Dropped"
@@ -75,6 +78,8 @@ def reduce(planes, device_prefix: str = "/device:") -> Optional[dict]:
                     if ev.name.startswith(SPAN_PREFIX):
                         spans.append((ev.name[len(SPAN_PREFIX):],
                                       ev.start_ns, ev.end_ns))
+                    elif ev.name.startswith(PROGRAM_PREFIX):
+                        spans.append((ev.name, ev.start_ns, ev.end_ns))
     windows = [(a, b) for name, a, b in spans if name == WINDOW]
     if not windows:
         return None
